@@ -25,10 +25,12 @@ import numpy as np
 
 from repro import configs
 from repro.configs.base import SHAPES
-from repro.launch.mesh import HW
+from repro.launch.mesh import hw_for
 
 DRYRUN_DIR = Path("experiments/dryrun")
 CHIPS_SINGLE = 256
+# the production pod the dry-run models is TPU v5e
+HW = hw_for("TPU v5 lite")
 
 
 def attention_adjustment(arch: str, shape_name: str) -> Dict[str, float]:

@@ -56,8 +56,6 @@ def compressed_allreduce_tree(tree, err_tree, mesh, axis_name: str,
                               token_spec):
     """Apply compressed mean-all-reduce to every leaf of ``tree`` (with
     error feedback from / into ``err_tree``), via one shard_map."""
-    from jax.experimental.shard_map import shard_map
-
     flat, treedef = jax.tree_util.tree_flatten(tree)
     errs = jax.tree_util.tree_leaves(err_tree) if err_tree is not None \
         else [jnp.zeros_like(x) for x in flat]
@@ -73,8 +71,8 @@ def compressed_allreduce_tree(tree, err_tree, mesh, axis_name: str,
         return tuple(outs) + tuple(new_errs)
 
     specs = tuple(token_spec for _ in flat)
-    res = shard_map(fn, mesh=mesh, in_specs=specs + specs,
-                    out_specs=specs + specs, check_rep=False)(*flat, *errs)
+    res = jax.shard_map(fn, mesh=mesh, in_specs=specs + specs,
+                        out_specs=specs + specs, check_vma=False)(*flat, *errs)
     out = jax.tree_util.tree_unflatten(treedef, res[:len(flat)])
     new_err = jax.tree_util.tree_unflatten(treedef, res[len(flat):])
     return out, new_err
@@ -89,8 +87,6 @@ def sp_decode_attention(q, k, v, mesh, *, seq_axis: str = "model",
     (max, exp-sum, weighted value); one psum pair combines them — the
     collective payload is O(B·H·D), independent of T.
     """
-    from jax.experimental.shard_map import shard_map
-
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     rep = Hq // Hkv
@@ -112,10 +108,10 @@ def sp_decode_attention(q, k, v, mesh, *, seq_axis: str = "model",
         out = acc / jnp.maximum(l[..., None], 1e-30)
         return out.reshape(B, S, Hq, D).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(PS(None, None, None, None),
                   PS(None, seq_axis, None, None),
                   PS(None, seq_axis, None, None)),
         out_specs=PS(None, None, None, None),
-        check_rep=False)(q, k, v)
+        check_vma=False)(q, k, v)

@@ -30,8 +30,6 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh, *,
     x_micro:      (n_micro, mb, ...) microbatched input, replicated.
     Returns (n_micro, mb, ...) outputs (valid on every device).
     """
-    from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape[stage_axis]
     n_micro = x_micro.shape[0]
     T = n_micro + n_stages - 1
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh, *,
 
     pspec = jax.tree_util.tree_map(
         lambda _: PS(stage_axis), stage_params)
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(pspec, PS()),
-                     out_specs=PS(),
-                     check_rep=False)(stage_params, x_micro)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(pspec, PS()),
+                         out_specs=PS(),
+                         check_vma=False)(stage_params, x_micro)

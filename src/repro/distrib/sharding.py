@@ -20,7 +20,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec)
 
 from ..models.common import Axes
 
@@ -28,16 +29,10 @@ Rules = Dict[str, Tuple[str, ...]]
 
 
 def abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-less mesh for spec resolution, across JAX API revisions.
-
-    Newer JAX takes ``AbstractMesh(((name, size), ...))``; older releases
-    took ``(shape, axis_names)`` positionally.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except (TypeError, ValueError):
-        return AbstractMesh(shape, axes)
+    """Device-less mesh for spec resolution (Auto axes, like the
+    production meshes of launch/mesh.py)."""
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        axis_types=(AxisType.Auto,) * len(axes))
 
 # rule values are *ordered preferences*; () / missing = replicate
 DEFAULT_RULES: Rules = {

@@ -7,18 +7,32 @@ chunk does the quadratic intra-chunk part on the MXU ((Q,N)·(N,Q),
 decomposition, with chunk length Q sized so the working set
 (Q² scores + state) fits VMEM.
 
+Layout is head-major: x as (B, H, S, P), B/C as (B, G, S, N) and dt as
+(B, H, 1, S), so every block ends in a (Q, P) / (Q, N) / (1, Q) tile.
+The within-chunk prefix sums of dt·A are triangular matmuls, giving the
+row and column orientations without a transpose.
+
 Padding trick: the sequence is padded with dt = 0 ⇒ decay 1, input
 contribution 0, so padded tail rows never perturb the state.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_NN = (((1,), (0,)), ((), ()))        # a @ b
+_NT = (((1,), (1,)), ((), ()))        # a @ b.T
+_TN = (((0,), (0,)), ((), ()))        # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(A_ref, D_ref, x_ref, dt_ref, B_ref, C_ref, h0_ref,
@@ -33,48 +47,43 @@ def _kernel(A_ref, D_ref, x_ref, dt_ref, B_ref, C_ref, h0_ref,
         else:
             h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)            # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)          # (Q,)
-    Bm = B_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
-    Cm = C_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
-    A = A_ref[h]
-
-    da = dt * A                                       # (Q,)
-    cum = jnp.cumsum(da)                              # inclusive
-    total = cum[-1]
-
-    # intra-chunk quadratic part
+    x = x_ref[0, 0].astype(jnp.float32)               # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)             # (1, Q)
+    Bm = B_ref[0, 0].astype(jnp.float32)              # (Q, N)
+    Cm = C_ref[0, 0].astype(jnp.float32)              # (Q, N)
     Q = x.shape[0]
-    diff = cum[:, None] - cum[None, :]
+
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.exp(jnp.where(ii >= jj, diff, -1e30))
-    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    scores = cb * decay * dt[None, :]
-    y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    lower = ii >= jj
+    tri = lower.astype(jnp.float32)
+    da = dt * A_ref[h]                                # (1, Q)
+    # inclusive prefix sums in both orientations (no vector transpose)
+    cum_row = _dot(da, tri, _NT)                      # (1, Q)
+    cum_col = _dot(tri, da, _NT)                      # (Q, 1)
+    total = jnp.sum(da)                               # scalar
+
+    # intra-chunk quadratic part
+    decay = jnp.exp(jnp.where(lower, cum_col - cum_row, -1e30))
+    scores = _dot(Cm, Bm, _NT) * decay * dt
+    y = _dot(scores, x, _NN)
 
     # inter-chunk: y += exp(cum) * C @ h^T   (h: (P,N))
     hs = h_ref[...]
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
-        Cm, hs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
+    y = y + jnp.exp(cum_col) * _dot(Cm, hs, _NT)
     if use_D:
         y = y + D_ref[h] * x
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: h = exp(total) h + sum_j exp(total - cum_j) dt_j x_j ⊗ B_j
-    w = jnp.exp(total - cum) * dt                     # (Q,)
-    contrib = jax.lax.dot_general(x * w[:, None], Bm,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    h_ref[...] = hs * jnp.exp(total) + contrib
+    dt_col = _dot((ii == jj).astype(jnp.float32), dt, _NT)   # (Q, 1)
+    w = jnp.exp(total - cum_col) * dt_col             # (Q, 1)
+    h_new = hs * jnp.exp(total) + _dot(x * w, Bm, _TN)
+    h_ref[...] = h_new
 
     @pl.when(ic == nc - 1)
     def _fin():
-        hf_ref[0, 0] = h_ref[...]
+        hf_ref[0, 0] = h_new
 
 
 def ssd_pallas(x, dt, A, B, C, D=None, h0=None, *, chunk: int = 256,
@@ -98,6 +107,11 @@ def ssd_pallas(x, dt, A, B, C, D=None, h0=None, *, chunk: int = 256,
     D_in = D if use_D else jnp.zeros((H,), jnp.float32)
     h0_in = h0 if use_h0 else jnp.zeros((Bb, H, P, N), jnp.float32)
 
+    xt = jnp.swapaxes(x, 1, 2)                         # (B, H, Sp, P)
+    dtt = jnp.swapaxes(dt, 1, 2)[:, :, None, :]        # (B, H, 1, Sp)
+    Bt = jnp.swapaxes(B, 1, 2)                         # (B, G, Sp, N)
+    Ct = jnp.swapaxes(C, 1, 2)
+
     kernel = functools.partial(_kernel, nc=nc, use_D=use_D, use_h0=use_h0)
     y, hf = pl.pallas_call(
         kernel,
@@ -105,24 +119,24 @@ def ssd_pallas(x, dt, A, B, C, D=None, h0=None, *, chunk: int = 256,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),     # A (H,)
             pl.BlockSpec(memory_space=pltpu.SMEM),     # D (H,)
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, Q, 1, N),
-                         lambda b, h, c, _r=rep: (b, c, h // _r, 0)),
-            pl.BlockSpec((1, Q, 1, N),
-                         lambda b, h, c, _r=rep: (b, c, h // _r, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, Q, N),
+                         lambda b, h, c, _r=rep: (b, h // _r, c, 0)),
+            pl.BlockSpec((1, 1, Q, N),
+                         lambda b, h, c, _r=rep: (b, h // _r, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bb, Sp, H, P), x.dtype),
+            jax.ShapeDtypeStruct((Bb, H, Sp, P), x.dtype),
             jax.ShapeDtypeStruct((Bb, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
     )(jnp.asarray(A, jnp.float32), jnp.asarray(D_in, jnp.float32),
-      x, dt, B, C, h0_in)
-    return y[:, :S], hf
+      xt, dtt, Bt, Ct, h0_in)
+    return jnp.swapaxes(y, 1, 2)[:, :S], hf

@@ -37,7 +37,7 @@ from repro import configs
 from repro.configs.base import ModelConfig, ParallelConfig, SHAPES, ShapeSpec
 from repro.distrib import merge_rules, tree_shardings, tree_specs
 from repro.distrib.sharding import DEFAULT_RULES, bytes_per_device
-from repro.launch.mesh import HW, dp_axes, make_production_mesh
+from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.models import Model, unzip
 from repro.models.moe import padded_experts
 from repro.train import optim

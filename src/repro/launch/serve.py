@@ -20,6 +20,7 @@ import numpy as np
 
 from repro import configs
 from repro.core.executor import Engine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model, unzip
 from repro.serve.engine import ServeEngine
 from repro.services import ServingGateway
@@ -58,6 +59,7 @@ def main(argv=None):
                          "REPRO_TRACE_SAMPLE, falling back to 0.01). "
                          "Sampled spans are served via dbg.trace")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_sample is not None:
         trace.configure(sample=args.trace_sample)

@@ -25,6 +25,7 @@ from repro import configs
 from repro.configs.base import ParallelConfig
 from repro.core.executor import Engine
 from repro.data.pipeline import SyntheticSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model, unzip
 from repro.services import (CheckpointClient, CheckpointServer,
                             DataFeedClient, DataFeedServer,
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-uri", default=None,
                     help="external checkpoint server URI (tcp://…)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
     model = Model(cfg)

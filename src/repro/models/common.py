@@ -10,6 +10,7 @@ params for the dry-run without allocating.
 """
 from __future__ import annotations
 
+import hashlib
 import math
 from typing import Any, Callable, Optional, Tuple
 
@@ -75,9 +76,13 @@ def stack_p(trees):
 # init helpers
 # ---------------------------------------------------------------------------
 def _key(rng, *path) -> jax.Array:
+    """Per-parameter key: the path folded in through a stable digest
+    (Python's ``hash`` of a str is salted per process, so it would give
+    every process different parameters)."""
     k = rng
     for p in path:
-        k = jax.random.fold_in(k, abs(hash(p)) % (2 ** 31))
+        digest = hashlib.blake2b(repr(p).encode(), digest_size=4).digest()
+        k = jax.random.fold_in(k, int.from_bytes(digest, "little") >> 1)
     return k
 
 

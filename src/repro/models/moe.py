@@ -197,7 +197,6 @@ def moe_apply(cfg: ModelConfig, params: dict, x, *,
         aux = _aux_from_stats(cfg, ls, ps, zs, t)
         return y.reshape(B, S, d), aux
 
-    from jax.experimental.shard_map import shard_map
     mesh = spmd.mesh
     tok = PS(spmd.token_axes)
     ex = spmd.expert_axis
@@ -233,7 +232,7 @@ def moe_apply(cfg: ModelConfig, params: dict, x, *,
         for k in shared_keys)
     expert_spec = PS(ex, None, None)               # ex=None -> replicated
 
-    y, ls, ps, zs, t = shard_map(
+    y, ls, ps, zs, t = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(PS(spmd.token_axes, None),
                   PS(None, None),
@@ -241,7 +240,7 @@ def moe_apply(cfg: ModelConfig, params: dict, x, *,
                   *shared_specs),
         out_specs=(PS(spmd.token_axes, None), PS(None), PS(None), PS(),
                    PS()),
-        check_rep=False,
+        check_vma=False,
     )(x2d, params["router"], params["wi_gate"], params["wi_up"],
       params["wo"], *shared_vals)
     aux = _aux_from_stats(cfg, ls, ps, zs, t)

@@ -86,6 +86,8 @@ class Request:
     t_admit: float = 0.0
     # monotonic time of the first emitted token (TTFT = t_first-t_submit)
     t_first: float = 0.0
+    # set when the engine failed the request instead of finishing it
+    error: Optional[str] = None
     _done_cbs: List[Callable[[], None]] = field(default_factory=list)  #: guarded-by _cb_lock
     _cb_lock: threading.Lock = field(default_factory=threading.Lock)
 
@@ -110,25 +112,30 @@ class Request:
 
 
 class ServeEngine:
+    """``device`` pins the engine: parameters, the batched cache, the B=1
+    staging cache and every jitted output live on that one device, so
+    one process can run a replica per chip.  ``None`` keeps JAX's
+    default placement."""
+
     def __init__(self, model: Model, params, *, max_len: int = 512,
                  n_slots: int = 4, seed: int = 0, impl: str = "auto",
                  chunk_tokens: int = 0, session_cap: int = 0,
-                 cache_dtype=None):
+                 cache_dtype=None, device=None):
         self.model = model
-        self.params = params
+        self.device = device
+        self.params = self._put(params)
         self.max_len = max_len
         self.n_slots = n_slots
         self.impl = impl
         self.cache_dtype = cache_dtype or jnp.bfloat16
-        cache_p = model.cache_specs(n_slots, max_len, dtype=self.cache_dtype)
-        self.cache, self.cache_axes = unzip(cache_p)
+        self.cache, self.cache_axes = self._zero_cache(n_slots)
         self.pos = np.zeros((n_slots,), np.int32)
         self.slot_req: List[Optional[Request]] = [None] * n_slots
         self.last_tok = np.zeros((n_slots,), np.int32)
         self.queue: "queue.Queue[Request]" = queue.Queue()
         # set on submit: idle step loops wait on this instead of polling
         self.work = threading.Event()
-        self._rng = jax.random.PRNGKey(seed)
+        self._rng = self._put(jax.random.PRNGKey(seed))
         self._rid = 0  #: guarded-by _lock
         self._lock = threading.Lock()
 
@@ -154,25 +161,27 @@ class ServeEngine:
         self.prefix_tokens_saved = 0
         self.session_evictions = 0
 
-        self._prefill_jit = jax.jit(
-            lambda p, b: self.model.prefill(p, b, cache_len=max_len,
-                                            impl=impl))
-        self._decode_jit = jax.jit(
-            lambda p, c, t, pos: self.model.decode_step(p, c, t, pos,
-                                                        impl=impl))
+        def prefill(p, b):
+            return model.prefill(p, b, cache_len=max_len, impl=impl)
+
+        def decode_step(p, c, t, pos):
+            return model.decode_step(p, c, t, pos, impl=impl)
+
+        def prefill_chunk(p, c, t, off):
+            return model.prefill_chunk(p, c, t, off, impl=impl)
+
+        self._prefill_jit = jax.jit(prefill)
+        self._decode_jit = jax.jit(decode_step)
         if self.chunk or self.session_cap:
-            self._chunk_jit = jax.jit(
-                lambda p, c, t, off: self.model.prefill_chunk(p, c, t, off,
-                                                              impl=impl))
+            self._chunk_jit = jax.jit(prefill_chunk)
             # zeroed B=1 staging cache, shared template for fresh prompts
-            self._cache1_zero, _ = unzip(
-                model.cache_specs(1, max_len, dtype=self.cache_dtype))
+            self._cache1_zero, _ = self._zero_cache(1)
 
         # slot gather/scatter as single jitted executables (slot index is
         # a traced scalar: one compile covers every slot).  Eagerly
         # dispatching one dynamic-slice per cache leaf costs milliseconds
         # per request on the resume path — comparable to the chunk itself
-        def _gather(cache, slot):
+        def gather_slot(cache, slot):
             def one(src, axes):
                 return jax.lax.dynamic_slice_in_dim(
                     src, slot, 1, axis=axes.index("batch"))
@@ -181,7 +190,7 @@ class ServeEngine:
                 is_leaf=lambda x: hasattr(x, "shape")
                 and not isinstance(x, dict))
 
-        def _scatter(cache, cache1, slot):
+        def scatter_slot(cache, cache1, slot):
             def one(dst, src, axes):
                 return jax.lax.dynamic_update_slice_in_dim(
                     dst, src.astype(dst.dtype), slot,
@@ -191,19 +200,32 @@ class ServeEngine:
                 is_leaf=lambda x: hasattr(x, "shape")
                 and not isinstance(x, dict))
 
-        self._gather_jit = jax.jit(_gather)
-        self._scatter_jit = jax.jit(_scatter)
+        self._gather_jit = jax.jit(gather_slot)
+        self._scatter_jit = jax.jit(scatter_slot)
+
+    # -------------------------------------------------------------- placement
+    def _put(self, x):
+        """Host values and trees onto the engine's device (committed, so
+        the jitted steps run there; default placement without one)."""
+        return jax.device_put(x, self.device)
+
+    def _zero_cache(self, batch: int):
+        """A zeroed (batch, max_len) cache, allocated on the device."""
+        with jax.default_device(self.device):
+            cache, axes = unzip(self.model.cache_specs(
+                batch, self.max_len, dtype=self.cache_dtype))
+        return self._put(cache), axes
 
     # ------------------------------------------------------------------ slots
     def _scatter_slot(self, cache, cache1, slot: int):
         """Insert a B=1 cache into the engine cache at ``slot`` (batch dim
         found via logical axes)."""
-        return self._scatter_jit(cache, cache1, jnp.int32(slot))
+        return self._scatter_jit(cache, cache1, np.int32(slot))
 
     def _gather_slot(self, slot: int):
         """Extract slot ``slot`` of the engine cache as a B=1 cache (the
         staging tree a resumed session's suffix chunks continue into)."""
-        return self._gather_jit(self.cache, jnp.int32(slot))
+        return self._gather_jit(self.cache, np.int32(slot))
 
     def submit(self, prompt, max_new: int = 32, temperature: float = 0.0,
                eos_id: int = -1, frontend=None,
@@ -345,9 +367,9 @@ class ServeEngine:
                 self._prefill_monolithic(slot, req)
 
     def _prefill_monolithic(self, slot: int, req: Request):
-        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+        batch = {"tokens": self._put(req.prompt[None, :])}
         if req.frontend is not None:
-            batch["frontend"] = jnp.asarray(req.frontend[None])
+            batch["frontend"] = self._put(req.frontend[None])
         logits, cache1 = self._prefill_jit(self.params, batch)
         self.cache = self._scatter_slot(self.cache, cache1, slot)
         tok = self._sample(logits[0], req)
@@ -382,10 +404,10 @@ class ServeEngine:
         cache into the slot and emit the first sampled token."""
         C = self.chunk or _RESUME_CHUNK
         req = st["req"]
-        chunk = jnp.asarray(st["toks"][st["off"]:st["off"] + C][None, :])
+        chunk = self._put(st["toks"][st["off"]:st["off"] + C][None, :])
         off = st["base"] + st["off"]
         logits, st["cache1"] = self._chunk_jit(self.params, st["cache1"],
-                                               chunk, jnp.int32(off))
+                                               chunk, np.int32(off))
         st["off"] += C
         if st["off"] < st["n"]:
             return
@@ -427,8 +449,10 @@ class ServeEngine:
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and i not in self._prefill]
         if active:
-            toks = jnp.asarray(self.last_tok[:, None])
-            pos = jnp.asarray(self.pos)
+            # copies: the host arrays advance while the step may still
+            # be reading its inputs
+            toks = self._put(self.last_tok[:, None].copy())
+            pos = self._put(self.pos.copy())
             logits, self.cache = self._decode_jit(self.params, self.cache,
                                                   toks, pos)
             for i in active:
@@ -443,6 +467,31 @@ class ServeEngine:
                 if req.done_event.is_set():
                     self._release_slot(i)
         return sum(1 for r in self.slot_req if r is not None)
+
+    def fail_all(self, reason: str) -> int:
+        """Fail every request the engine holds — decoding, mid-prefill,
+        pending or queued — with ``reason``, and drop the pinned
+        sessions (a step that raised may have left any slot's KV half
+        written).  Step-thread only.  Returns how many were failed."""
+        reqs = [r for r in self.slot_req if r is not None]
+        reqs += list(self._pending)
+        while True:
+            try:
+                reqs.append(self.queue.get_nowait())
+            except queue.Empty:
+                break
+        self._pending.clear()
+        self._prefill.clear()
+        self.slot_req = [None] * self.n_slots
+        self.slot_session = [None] * self.n_slots
+        self.sessions.clear()
+        self.pos[:] = 0
+        self.last_tok[:] = 0
+        for req in reqs:
+            req.error = reason
+            req.done_event.set()
+            req._fire_done()
+        return len(reqs)
 
     def drain(self):
         """Run steps until queue and slots are empty (pinned sessions

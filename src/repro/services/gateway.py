@@ -24,7 +24,10 @@ RPCs:
                    piggybacked balancing signal) + admission stats
 
 A background thread drives ``ServeEngine.step()`` whenever work exists
-(woken by the engine's work event — no idle polling); with ``registry=``
+(woken by the engine's work event — no idle polling).  If a step raises,
+the loop records the fault (``faults``/``last_fault`` in ``gen.stats``),
+fails every request the engine held with ``Ret.FAULT`` and keeps
+serving; with ``registry=``
 (one endpoint or the comma-separated replica set of a registry quorum —
 see DESIGN.md §8) the gateway self-registers as an instance of service
 ``service`` and reports its load, making it routable through a
@@ -42,6 +45,7 @@ deadlines, and the client pool re-routes the shed ones immediately.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Dict, Optional
@@ -50,7 +54,7 @@ import numpy as np
 
 from ..core.bulk import BulkDescriptor
 from ..core.executor import Engine
-from ..core.types import Ret
+from ..core.types import MercuryError, Ret
 from ..serve.engine import Request, ServeEngine
 from ..telemetry import metrics as _metrics
 from ..telemetry import trace as _trace
@@ -63,6 +67,9 @@ _M_COMPLETIONS = _metrics.counter("service.gateway.completions")
 _M_TOKENS_OUT = _metrics.counter("service.gateway.tokens_out")
 _M_QUEUE_MS = _metrics.histogram("service.gateway.queue_ms")
 _M_SERVICE_MS = _metrics.histogram("service.gateway.service_ms")
+_M_FAULTS = _metrics.counter("service.gateway.step_faults")
+
+_log = logging.getLogger(__name__)
 
 
 class ServingGateway:
@@ -79,6 +86,8 @@ class ServingGateway:
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self.steps = 0  #: guarded-by _lock
+        self.faults = 0  #: guarded-by _lock
+        self.last_fault: Optional[str] = None  #: guarded-by _lock
         self.admission = admission or AdmissionController()
         self.shed_enabled = shed_enabled
         engine.register("gen.submit", self._submit, pass_handle=True)
@@ -211,12 +220,13 @@ class ServingGateway:
 
     def _result_payload(self, rid: int, req: Request) -> dict:
         done = req.done_event.is_set()
-        out = {"tokens": list(req.out_tokens), "done": done,
-               "ttft_ms": self._ttft_ms(req)}
         if done:
             with self._lock:
                 self.requests.pop(rid, None)
-        return out
+        if req.error is not None:
+            raise MercuryError(Ret.FAULT, f"rid {rid}: {req.error}")
+        return {"tokens": list(req.out_tokens), "done": done,
+                "ttft_ms": self._ttft_ms(req)}
 
     def _result(self, req_in, handle):
         rid = int(req_in["rid"])
@@ -242,13 +252,14 @@ class ServingGateway:
             try:
                 handle.respond(self._result_payload(rid, req))
             except Exception as e:
-                # e.g. MSGSIZE on a huge token payload: report instead of
-                # letting the error escape into the caller's thread (the
-                # serve step loop or the progress thread's deadline sweep)
+                # a failed request, or e.g. MSGSIZE on a huge token
+                # payload: report instead of letting the error escape into
+                # the caller's thread (the serve step loop or the progress
+                # thread's deadline sweep)
                 try:
                     if not handle.responded:
                         handle.respond(f"{type(e).__name__}: {e}",
-                                       ret=Ret.FAULT)
+                                       ret=getattr(e, "ret", Ret.FAULT))
                 except Exception:
                     pass
 
@@ -267,6 +278,8 @@ class ServingGateway:
         req.done_event.wait(float(req_in.get("timeout", 120.0)))
         with self._lock:
             self.requests.pop(req.rid, None)
+        if req.error is not None:
+            raise MercuryError(Ret.FAULT, f"rid {req.rid}: {req.error}")
         return {"tokens": list(req.out_tokens),
                 "done": req.done_event.is_set(),
                 "ttft_ms": self._ttft_ms(req)}
@@ -274,28 +287,49 @@ class ServingGateway:
     def _stats(self, _req):
         out = self.serve.stats()
         with self._lock:
-            steps = self.steps
+            steps, faults, last_fault = self.steps, self.faults, \
+                self.last_fault
         lookups = out["prefix_hits"] + out["prefix_misses"]
-        out.update(steps=steps, uris=self.engine.uri,
+        out.update(steps=steps, faults=faults, last_fault=last_fault,
+                   uris=self.engine.uri,
                    load=self._load(),
                    prefix_hit_rate=(out["prefix_hits"] / lookups
                                     if lookups else 0.0),
                    **self.admission.stats())
         return out
 
+    def _on_fault(self, exc: Exception) -> None:
+        """A step raised (called from its ``except``): record it and fail
+        what the engine held, so waiting clients get ``Ret.FAULT`` now
+        instead of a timeout."""
+        reason = f"{type(exc).__name__}: {exc}"
+        _log.exception("serve step failed; failing in-flight requests")
+        _M_FAULTS.inc()
+        with self._lock:
+            self.faults += 1
+            self.last_fault = reason
+        self.serve.fail_all(f"serve step failed: {reason}")
+
     def _loop(self):
         while not self._stop.is_set():
-            n = self.serve.step()
+            faulted = False
+            try:
+                n = self.serve.step()
+            except Exception as e:
+                self._on_fault(e)
+                n, faulted = 0, True
             if n:
                 with self._lock:
                     self.steps += 1
             if n == 0 and self.serve.pending() == 0:
                 # park until the next submit (double-check after clearing
                 # so a racing submit can't be missed; the bounded wait
-                # caps the cost of any residual race)
+                # caps the cost of any residual race).  After a fault the
+                # engine holds nothing: wait for a submit, never re-run a
+                # failing step on an idle tick
                 self.serve.work.clear()
                 if self.serve.pending() == 0 and not self._stop.is_set():
-                    self.serve.work.wait(0.05)
+                    self.serve.work.wait(None if faulted else 0.05)
 
     def close(self):
         """Graceful stop: deregister from the fabric and join the step

@@ -78,11 +78,10 @@ QUANT_SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as PS
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import AxisType, PartitionSpec as PS
     from repro.distrib.collectives import compressed_psum
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
     def f(x_l):
@@ -90,9 +89,9 @@ QUANT_SCRIPT = textwrap.dedent("""
         return out[None], err[None]
 
     with mesh:
-        out, err = jax.jit(shard_map(f, mesh=mesh, in_specs=(PS("data"),),
-                                     out_specs=(PS("data"), PS("data")),
-                                     check_rep=False))(x)
+        out, err = jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=(PS("data"),),
+            out_specs=(PS("data"), PS("data")), check_vma=False))(x)
     want = np.asarray(x.mean(0))
     got = np.asarray(out[0])
     # int8 with a shared per-tensor scale: per-element error bounded by
@@ -122,10 +121,11 @@ QUANT_SCRIPT = textwrap.dedent("""
             out, new_err = compressed_psum(g + err[0], "data")
             return out[None], new_err[None]
         with mesh:
-            g, e = shard_map(f, mesh=mesh,
-                             in_specs=(PS("data"), PS("data"), PS("data")),
-                             out_specs=(PS("data"), PS("data")),
-                             check_rep=False)(Xd, yd, e)
+            g, e = jax.shard_map(f, mesh=mesh,
+                                 in_specs=(PS("data"), PS("data"),
+                                           PS("data")),
+                                 out_specs=(PS("data"), PS("data")),
+                                 check_vma=False)(Xd, yd, e)
         return w - 0.3 * g[0], e
 
     w1 = jnp.zeros(8); w2 = jnp.zeros(8); e = jnp.zeros((4, 8))
@@ -154,7 +154,8 @@ PIPE_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.distrib.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
     n_stages, n_micro, mb, d = 4, 8, 2, 16
     Ws = jax.random.normal(jax.random.PRNGKey(0), (n_stages, d, d)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, d))
@@ -190,7 +191,9 @@ SP_DECODE_SCRIPT = textwrap.dedent("""
     from repro.distrib.collectives import sp_decode_attention
     from repro.kernels import ref
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     B, T, Hq, Hkv, D = 2, 64, 4, 2, 16
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(k1, (B, 1, Hq, D))

@@ -138,6 +138,22 @@ def test_rglru_pallas_vs_ref(case):
     np.testing.assert_allclose(hxf, hrf, rtol=2e-4, atol=2e-4)
 
 
+def test_rglru_pallas_bfloat16_rows():
+    """16-bit inputs walk 16-row groups; the state stays float32, so the
+    output matches the reference up to bfloat16 rounding of inputs and
+    outputs."""
+    B, S, W = 2, 40, 24
+    x, rg, ig = (t(B, S, W).astype(jnp.bfloat16) for _ in range(3))
+    ll, h0 = t(W), t(B, W) * 0.2
+    hr, hrf = ref.rglru_ref(*(a.astype(jnp.float32) for a in (x, rg, ig)),
+                            ll, h0)
+    hp, hpf = rglru_pallas(x, rg, ig, ll, h0, interpret=True, block_t=32)
+    assert hp.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(hp, np.float32), hr,
+                               rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(hpf, hrf, rtol=1e-4, atol=1e-4)
+
+
 @cases(10)
 def test_rglru_stability_property(rng):
     """|h| stays bounded: a ∈ (0,1) and beta = sqrt(1-a²) normalizes."""

@@ -138,3 +138,35 @@ def test_moe_active_params():
     assert g.active_param_count() < 0.35 * g.param_count()
     d = configs.get("deepseek-moe-16b")
     assert 2.0e9 < d.active_param_count() < 3.5e9
+
+
+CHECKSUM_SCRIPT = """
+import sys
+sys.path.insert(0, "src")
+import jax, numpy as np
+from repro import configs
+from repro.models import Model, unzip
+params, _ = unzip(Model(configs.reduced("qwen1.5-0.5b")).init(
+    jax.random.PRNGKey(0)))
+leaves = jax.tree_util.tree_leaves(params)
+print("SUM", repr(float(sum(np.abs(np.asarray(x, np.float64)).sum()
+                            for x in leaves))))
+"""
+
+
+def test_param_init_same_in_every_process():
+    """init(PRNGKey(0)) gives the same parameters in every process, whatever
+    the interpreter's per-process string-hash salt."""
+    import os
+    import subprocess
+    import sys
+    sums = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu")
+        r = subprocess.run([sys.executable, "-c", CHECKSUM_SCRIPT],
+                           capture_output=True, text=True, timeout=300,
+                           env=env)
+        line = [ln for ln in r.stdout.splitlines() if ln.startswith("SUM")]
+        assert line, r.stdout + r.stderr
+        sums.append(line[0])
+    assert sums[0] == sums[1], sums

@@ -117,7 +117,9 @@ SPMD_SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
     y_local, aux_local = moe_mod.moe_apply(cfg, params, x, dropless=True)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     spmd = moe_mod.MoESpmd(mesh=mesh, token_axes=("data",),
                            expert_axis="model")
     with mesh:

@@ -11,7 +11,8 @@ import pytest
 
 from repro import configs
 from repro.configs.base import ParallelConfig
-from repro.core.executor import Engine
+from repro.core.executor import Engine, RemoteError
+from repro.core.types import Ret
 from repro.models import Model, unzip
 from repro.serve.engine import ServeEngine
 from repro.services import (CheckpointClient, CheckpointServer,
@@ -85,6 +86,57 @@ def test_gateway_sm_bulk_submit(model_and_params):
         assert res["done"] and len(res["tokens"]) == 4
         stats = cli.call(srv.uri, "gen.stats", {})
         assert "sm://" in stats["uris"]
+        gw.stop()
+
+
+def test_gateway_step_fault_fails_requests(model_and_params):
+    """A step that raises must not kill the gateway's loop silently:
+    waiting gen.generate and gen.result callers get FAULT at once, the
+    fault shows in gen.stats, and the gateway keeps serving."""
+    m, params = model_and_params
+    with Engine("tcp://127.0.0.1:0") as srv, \
+            Engine("tcp://127.0.0.1:0") as cli:
+        serve = ServeEngine(m, params, max_len=64, n_slots=2)
+        real_step = serve.step
+        raised = threading.Event()
+
+        def flaky_step():
+            # fail the first step that has a request to serve
+            if serve.pending() and not raised.is_set():
+                raised.set()
+                raise RuntimeError("injected step failure")
+            return real_step()
+
+        serve.step = flaky_step
+        gw = ServingGateway(srv, serve)
+        sub = cli.call(srv.uri, "gen.submit",
+                       {"tokens": [4, 5, 6], "max_new": 3})
+        t0 = time.monotonic()
+        with pytest.raises(RemoteError) as ei:
+            cli.call(srv.uri, "gen.result",
+                     {"rid": sub["rid"], "wait": True, "timeout": 60.0},
+                     timeout=60.0)
+        assert ei.value.ret == Ret.FAULT
+        assert "injected step failure" in str(ei.value)
+        assert time.monotonic() - t0 < 30.0
+        stats = cli.call(srv.uri, "gen.stats", {})
+        assert stats["faults"] == 1
+        assert "injected step failure" in stats["last_fault"]
+        # the loop survived: the next request is served normally
+        out = cli.call(srv.uri, "gen.generate",
+                       {"tokens": [1, 2, 3], "max_new": 3}, timeout=120.0)
+        assert out["done"] and len(out["tokens"]) == 3
+
+        def broken_step():
+            raise ValueError("broken step")
+
+        serve.step = broken_step
+        with pytest.raises(RemoteError) as ei:
+            cli.call(srv.uri, "gen.generate",
+                     {"tokens": [1, 2, 3], "max_new": 3}, timeout=60.0)
+        assert ei.value.ret == Ret.FAULT and "broken step" in str(ei.value)
+        time.sleep(0.2)        # an idle loop must not re-run the failing step
+        assert cli.call(srv.uri, "gen.stats", {})["faults"] == 2
         gw.stop()
 
 

@@ -13,7 +13,7 @@ SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as PS
     from repro import configs
     from repro.configs.base import ParallelConfig
     from repro.models import Model, unzip
@@ -22,7 +22,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train import optim
     from repro.train.step import init_state, make_train_step
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     import dataclasses
     for arch in ["qwen1.5-0.5b", "granite-moe-3b-a800m"]:

@@ -17,6 +17,8 @@ from repro.fabric import (RegistryClient, RegistryService, RetryPolicy,
 from repro.telemetry import metrics, trace
 from repro.telemetry.metrics import MetricsRegistry
 
+from conftest import poll_until
+
 LEASE = 0.5
 GOSSIP = 0.12
 
@@ -348,9 +350,17 @@ def test_dbg_trace_reassembles_across_processes(traced, tmp_path):
             with trace.use(root.ctx):
                 assert cli.call(uri, "work", 21, timeout=20.0) == 42
             root.finish("OK")
-            remote = cli.call(uri, "dbg.trace",
-                              {"trace_id": root.ctx.trace_hex},
-                              timeout=20.0)
+
+            def fetch():
+                r = cli.call(uri, "dbg.trace",
+                             {"trace_id": root.ctx.trace_hex}, timeout=20.0)
+                return r if any(s["name"] == "rpc.work"
+                                for s in r["spans"]) else None
+
+            # the server span is recorded after the response is sent:
+            # under load dbg.trace can arrive before it
+            remote = poll_until(fetch, timeout=10.0,
+                                msg="the worker's rpc.work span")
         assert remote["pid"] != os.getpid()
         spans = trace.spans_for(root.ctx.trace_hex) + remote["spans"]
         roots, _ = trace.build_tree(spans)

@@ -1,0 +1,146 @@
+"""Compile the serving path's kernels and steps for a described TPU v5e.
+
+No chip is attached: the TPU compiler runs against a ``v5e:2x2``
+topology described inside a module fixture, so what Mosaic or XLA would
+refuse on the chip (a block not aligned to the tiling, an unsupported
+primitive, a program over the device's memory) fails here.  Nothing
+runs; the tests say nothing about results or times.  Kernels get shapes
+at the published widths of the model that uses them.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.moe_router import router_topk_pallas
+from repro.kernels.rglru_scan import rglru_pallas
+from repro.kernels.ssd import ssd_pallas
+from repro.models import Model, unzip
+
+HBM_BYTES = 16 * 2 ** 30        # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2, with the persistent compile
+    cache off (an entry compiled for a described chip cannot be read
+    back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _qwen_shapes(one_chip, batch, cache_len):
+    model = Model(configs.get("qwen1.5-0.5b"))
+    params = _on(one_chip, unzip(jax.eval_shape(model.init,
+                                                jax.random.PRNGKey(0)))[0])
+    cache = _on(one_chip, unzip(jax.eval_shape(
+        lambda: model.cache_specs(batch, cache_len)))[0])
+    return model, params, cache
+
+
+@pytest.mark.parametrize("seq", [128, 512])
+def test_flash_attention_qwen_width(one_chip, seq):
+    cfg = configs.get("qwen1.5-0.5b")
+    q = _sds(one_chip, (1, seq, cfg.n_heads, cfg.hd))
+    kv = _sds(one_chip, (1, seq, cfg.n_kv_heads, cfg.hd))
+    c = _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                 q, kv, kv)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_qwen_prefill_holds_flash_kernel(one_chip):
+    model, params, _ = _qwen_shapes(one_chip, 1, 1024)
+    tokens = _sds(one_chip, (1, 128), jnp.int32)
+    c = _compile(lambda p, t: model.prefill(p, {"tokens": t},
+                                            cache_len=1024, impl="pallas"),
+                 params, tokens)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_qwen_decode_step_fits_one_chip(one_chip):
+    model, params, cache = _qwen_shapes(one_chip, 8, 1024)
+    toks = _sds(one_chip, (8, 1), jnp.int32)
+    pos = _sds(one_chip, (8,), jnp.int32)
+    c = _compile(lambda p, c, t, q: model.decode_step(p, c, t, q,
+                                                      impl="pallas"),
+                 params, cache, toks, pos)
+    m = c.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_router_topk_granite_width(one_chip):
+    cfg = configs.get("granite-moe-3b-a800m")
+    logits = _sds(one_chip, (4096, cfg.moe.num_experts), jnp.float32)
+    c = _compile(lambda x: router_topk_pallas(x, cfg.moe.top_k), logits)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_ssd_mamba2_width(one_chip):
+    cfg = configs.get("mamba2-1.3b")
+    ssm = cfg.ssm
+    H = ssm.expand * cfg.d_model // ssm.head_dim
+    B, S, G = 1, 512, ssm.ngroups
+    args = (_sds(one_chip, (B, S, H, ssm.head_dim)),
+            _sds(one_chip, (B, S, H)),
+            _sds(one_chip, (H,), jnp.float32),
+            _sds(one_chip, (B, S, G, ssm.state_dim)),
+            _sds(one_chip, (B, S, G, ssm.state_dim)),
+            _sds(one_chip, (H,), jnp.float32))
+    c = _compile(lambda x, dt, A, Bm, Cm, D: ssd_pallas(
+        x, dt, A, Bm, Cm, D, chunk=ssm.chunk), *args)
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+def test_rglru_recurrentgemma_width(one_chip, batch):
+    W = configs.get("recurrentgemma-9b").rglru.lru_width
+    x = _sds(one_chip, (batch, 512, W))
+    args = (x, x, x, _sds(one_chip, (W,), jnp.float32),
+            _sds(one_chip, (batch, W), jnp.float32))
+    c = _compile(lambda x, r, i, ll, h0: rglru_pallas(x, r, i, ll, h0),
+                 *args)
+    assert "tpu_custom_call" in c.as_text()
+
+
+
+def test_peak_table_keyed_by_device_kind(one_chip):
+    """The roofline peaks are found under the kind JAX reports for a v5e,
+    and an unknown kind is an error, never a default."""
+    from repro.launch.mesh import hw_for
+    kind = next(iter(one_chip.device_set)).device_kind
+    assert hw_for(kind)["peak_flops_bf16"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        hw_for("TPU v0 unknown")
