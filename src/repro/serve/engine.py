@@ -29,6 +29,13 @@ chunk path, at an offset).  Pinned slots are evicted LRU-first whenever
 a fresh request needs a slot or the table exceeds ``session_cap``;
 correctness never depends on the cache (a miss is just a full prefill).
 
+**Phases and work counters**: ``step()`` runs in named phases
+(``PHASES``: admit, slot copy, prefill chunk, decode, sample, all inside
+``serve.step``), each a profiler annotation on the device trace's clock
+and a pair of ``serve.engine.phase_*`` counters; ``stats()`` carries the
+per-engine totals with the decode steps and tokens and the prefill
+chunks and real tokens they moved.
+
 The Mercury serving gateway (services/gateway.py) drives this engine from
 RPC handlers; ``generate()`` is the synchronous convenience wrapper used
 by examples and tests.
@@ -49,15 +56,27 @@ import numpy as np
 from ..models import Model, unzip
 from ..models.common import P, is_p
 from ..telemetry import metrics as _metrics
+from ..telemetry.phases import Phases
 
 # unified metrics (fab.metrics exports these; the per-engine view is in
-# stats()/gen.stats): session-reuse effectiveness + slot pressure
+# stats()/gen.stats): session reuse and the step loop's work
 _M_PREFIX_HITS = _metrics.counter("serve.engine.prefix_hits")
 _M_PREFIX_MISSES = _metrics.counter("serve.engine.prefix_misses")
 _M_TOKENS_SAVED = _metrics.counter("serve.engine.prefix_tokens_saved")
 _M_EVICTIONS = _metrics.counter("serve.engine.session_evictions")
-_G_OCCUPANCY = _metrics.gauge("serve.engine.occupancy")
-_G_PINNED = _metrics.gauge("serve.engine.pinned_sessions")
+_M_DECODE_STEPS = _metrics.counter("serve.engine.decode_steps")
+_M_DECODE_TOKENS = _metrics.counter("serve.engine.decode_tokens")
+_M_PREFILL_CHUNKS = _metrics.counter("serve.engine.prefill_chunks")
+_M_PREFILL_TOKENS = _metrics.counter("serve.engine.prefill_tokens")
+
+# the phases of step(), each a profiler annotation plus a pair of
+# counters by phase; all nest in serve.step
+PHASES = ("serve.step", "serve.admit", "serve.slot_copy",
+          "serve.prefill_chunk", "serve.decode", "serve.sample")
+_M_PHASE_NS = {p: _metrics.counter("serve.engine.phase_ns", phase=p)
+               for p in PHASES}
+_M_PHASE_CALLS = {p: _metrics.counter("serve.engine.phase_calls", phase=p)
+                  for p in PHASES}
 
 # chunk size used for session *resume* when chunked prefill is otherwise
 # disabled (the resume path is built on prefill-at-an-offset)
@@ -160,6 +179,11 @@ class ServeEngine:
         self.prefix_misses = 0
         self.prefix_tokens_saved = 0
         self.session_evictions = 0
+        self.decode_steps = 0
+        self.decode_tokens = 0        # sampled from decode steps
+        self.prefill_chunks = 0
+        self.prefill_tokens = 0       # real (unpadded) tokens of chunks
+        self._phase = Phases(_M_PHASE_NS, _M_PHASE_CALLS)
 
         def prefill(p, b):
             return model.prefill(p, b, cache_len=max_len, impl=impl)
@@ -220,12 +244,14 @@ class ServeEngine:
     def _scatter_slot(self, cache, cache1, slot: int):
         """Insert a B=1 cache into the engine cache at ``slot`` (batch dim
         found via logical axes)."""
-        return self._scatter_jit(cache, cache1, np.int32(slot))
+        with self._phase("serve.slot_copy"):
+            return self._scatter_jit(cache, cache1, np.int32(slot))
 
     def _gather_slot(self, slot: int):
         """Extract slot ``slot`` of the engine cache as a B=1 cache (the
         staging tree a resumed session's suffix chunks continue into)."""
-        return self._gather_jit(self.cache, np.int32(slot))
+        with self._phase("serve.slot_copy"):
+            return self._gather_jit(self.cache, np.int32(slot))
 
     def submit(self, prompt, max_new: int = 32, temperature: float = 0.0,
                eos_id: int = -1, frontend=None,
@@ -256,22 +282,24 @@ class ServeEngine:
 
     def stats(self) -> Dict[str, Any]:
         busy = sum(1 for r in self.slot_req if r is not None)
-        pinned = len(self.sessions)
-        occupancy = busy / max(self.n_slots, 1)
-        _G_OCCUPANCY.set(occupancy)
-        _G_PINNED.set(pinned)
         return {"active_slots": busy,
                 "n_slots": self.n_slots, "queued": self.pending(),
                 "max_len": self.max_len,
-                "occupancy": occupancy,
+                "occupancy": busy / max(self.n_slots, 1),
                 "prefilling": len(self._prefill),
-                "pinned_sessions": pinned,
+                "pinned_sessions": len(self.sessions),
                 "session_capacity": self.session_cap,
                 "session_evictions": self.session_evictions,
                 "chunk_tokens": self.chunk,
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
-                "prefix_tokens_saved": self.prefix_tokens_saved}
+                "prefix_tokens_saved": self.prefix_tokens_saved,
+                "decode_steps": self.decode_steps,
+                "decode_tokens": self.decode_tokens,
+                "prefill_chunks": self.prefill_chunks,
+                "prefill_tokens": self.prefill_tokens,
+                "phase_ns": dict(self._phase.ns),
+                "phase_calls": dict(self._phase.calls)}
 
     # ---------------------------------------------------------------- sessions
     def _evict(self, sid: str) -> int:
@@ -372,13 +400,14 @@ class ServeEngine:
             batch["frontend"] = self._put(req.frontend[None])
         logits, cache1 = self._prefill_jit(self.params, batch)
         self.cache = self._scatter_slot(self.cache, cache1, slot)
-        tok = self._sample(logits[0], req)
-        prompt_span = len(req.prompt) + (
-            self.model.cfg.frontend_seq
-            if req.frontend is not None else 0)
-        self.pos[slot] = prompt_span
-        self.last_tok[slot] = tok
-        self._emit(req, tok)
+        with self._phase("serve.sample"):
+            tok = self._sample(logits[0], req)
+            prompt_span = len(req.prompt) + (
+                self.model.cfg.frontend_seq
+                if req.frontend is not None else 0)
+            self.pos[slot] = prompt_span
+            self.last_tok[slot] = tok
+            self._emit(req, tok)
         if req.done_event.is_set():
             self._release_slot(slot)
 
@@ -404,10 +433,16 @@ class ServeEngine:
         cache into the slot and emit the first sampled token."""
         C = self.chunk or _RESUME_CHUNK
         req = st["req"]
-        chunk = self._put(st["toks"][st["off"]:st["off"] + C][None, :])
-        off = st["base"] + st["off"]
-        logits, st["cache1"] = self._chunk_jit(self.params, st["cache1"],
-                                               chunk, np.int32(off))
+        with self._phase("serve.prefill_chunk"):
+            chunk = self._put(st["toks"][st["off"]:st["off"] + C][None, :])
+            off = st["base"] + st["off"]
+            logits, st["cache1"] = self._chunk_jit(
+                self.params, st["cache1"], chunk, np.int32(off))
+        real = min(C, st["n"] - st["off"])
+        self.prefill_chunks += 1
+        self.prefill_tokens += real
+        _M_PREFILL_CHUNKS.inc()
+        _M_PREFILL_TOKENS.inc(real)
         st["off"] += C
         if st["off"] < st["n"]:
             return
@@ -416,9 +451,10 @@ class ServeEngine:
         last = st["n"] - 1 - (st["off"] - C)   # last real token, this chunk
         self.cache = self._scatter_slot(self.cache, st["cache1"], slot)
         self.pos[slot] = st["base"] + st["n"]
-        tok = self._sample(logits[0, last], req)
-        self.last_tok[slot] = tok
-        self._emit(req, tok)
+        with self._phase("serve.sample"):
+            tok = self._sample(logits[0, last], req)
+            self.last_tok[slot] = tok
+            self._emit(req, tok)
         if req.done_event.is_set():
             self._release_slot(slot)
 
@@ -443,30 +479,52 @@ class ServeEngine:
         """One engine step: admit, advance one prefill chunk per
         prefilling slot, one decode step for all decoding slots; returns
         #occupied slots (decoding + mid-prefill)."""
-        self._admit()
-        for slot in list(self._prefill):
-            self._prefill_step(slot, self._prefill[slot])
-        active = [i for i, r in enumerate(self.slot_req)
-                  if r is not None and i not in self._prefill]
-        if active:
+        with self._phase("serve.step"):
+            with self._phase("serve.admit"):
+                self._admit()
+            for slot in list(self._prefill):
+                self._prefill_step(slot, self._prefill[slot])
+            active = [i for i, r in enumerate(self.slot_req)
+                      if r is not None and i not in self._prefill]
+            if active:
+                self._decode(active)
+            return sum(1 for r in self.slot_req if r is not None)
+
+    def _decode(self, active: List[int]) -> None:
+        """One decode step for the ``active`` slots, then sample and emit
+        each slot's token."""
+        with self._phase("serve.decode"):
             # copies: the host arrays advance while the step may still
             # be reading its inputs
             toks = self._put(self.last_tok[:, None].copy())
             pos = self._put(self.pos.copy())
             logits, self.cache = self._decode_jit(self.params, self.cache,
                                                   toks, pos)
+            # the first sample's int() would block on these logits
+            # anyway; waiting here keeps the device's decode inside
+            # serve.decode and only the host's per-slot work in
+            # serve.sample
+            jax.block_until_ready(logits)
+        sampled = 0
+        with self._phase("serve.sample"):
             for i in active:
                 req = self.slot_req[i]
                 if req.done_event.is_set():
                     self._release_slot(i)
                     continue
                 tok = self._sample(logits[i], req)
+                sampled += 1
                 self.pos[i] += 1
                 self.last_tok[i] = tok
                 self._emit(req, tok)
                 if req.done_event.is_set():
                     self._release_slot(i)
-        return sum(1 for r in self.slot_req if r is not None)
+        # counted together, after the step, so that a stats() snapshot
+        # taken from another thread sees whole steps
+        self.decode_steps += 1
+        self.decode_tokens += sampled
+        _M_DECODE_STEPS.inc()
+        _M_DECODE_TOKENS.inc(sampled)
 
     def fail_all(self, reason: str) -> int:
         """Fail every request the engine holds — decoding, mid-prefill,

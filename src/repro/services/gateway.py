@@ -66,7 +66,6 @@ _M_SUBMITS = _metrics.counter("service.gateway.submits")
 _M_COMPLETIONS = _metrics.counter("service.gateway.completions")
 _M_TOKENS_OUT = _metrics.counter("service.gateway.tokens_out")
 _M_QUEUE_MS = _metrics.histogram("service.gateway.queue_ms")
-_M_SERVICE_MS = _metrics.histogram("service.gateway.service_ms")
 _M_FAULTS = _metrics.counter("service.gateway.step_faults")
 
 _log = logging.getLogger(__name__)
@@ -176,7 +175,6 @@ class ServingGateway:
             _M_COMPLETIONS.inc()
             _M_TOKENS_OUT.inc(len(req.out_tokens))
             _M_QUEUE_MS.observe(queue_s * 1e3)
-            _M_SERVICE_MS.observe(service_s * 1e3)
             if span.recorded:
                 span.annotate(rid=req.rid,
                               queue_ms=round(queue_s * 1e3, 3),

@@ -11,7 +11,7 @@ from repro import configs
 from repro.fabric import SessionAffinity
 from repro.fabric.balancer import prefer_instance
 from repro.models import Model, unzip
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import PHASES, ServeEngine
 from repro.services import ServingGateway
 
 CFG = configs.reduced("qwen1.5-0.5b").replace(compute_dtype="float32")
@@ -212,6 +212,41 @@ def test_sessions_disabled_on_unchunkable_model(model_and_params,
     st = eng.stats()
     assert st["pinned_sessions"] == 0
     assert st["prefix_hits"] == 0 and st["prefix_misses"] == 0
+
+
+# ------------------------------------------------------ counters, phases
+def test_work_counters_and_phases(model_and_params):
+    """The engine's work counters count what it served: one first token
+    per request comes from its prefill, every other from a decode step;
+    the chunk path carries every real prompt token (of a resumed
+    session, only the suffix) in ceil(n / C) chunks; every phase ran."""
+    m, params = model_and_params
+    C = 8
+    eng = make_engine(m, params, n_slots=3, chunk_tokens=C, session_cap=4)
+    prompts = [np.arange(1, 20), np.arange(2, 7), np.arange(3, 19)]
+    outs = eng.generate(prompts, max_new=4, session_ids=["a", None, None])
+    st = eng.stats()
+    assert st["decode_tokens"] + len(prompts) == sum(map(len, outs))
+    assert st["decode_steps"] >= max(map(len, outs)) - 1
+    assert st["prefill_tokens"] == sum(map(len, prompts))
+    assert st["prefill_chunks"] == sum(-(-len(p) // C) for p in prompts)
+
+    follow = np.concatenate([prompts[0], np.asarray(outs[0], np.int32),
+                             np.asarray([7, 9], np.int32)])
+    out = eng.generate([follow], max_new=3, session_ids=["a"])[0]
+    st2 = eng.stats()
+    assert st2["prefix_hits"] == 1
+    suffix = len(follow) - st2["prefix_tokens_saved"]
+    assert st2["prefill_tokens"] - st["prefill_tokens"] == suffix
+    assert st2["prefill_chunks"] - st["prefill_chunks"] == -(-suffix // C)
+    assert st2["decode_tokens"] - st["decode_tokens"] == len(out) - 1
+    assert set(st2["phase_calls"]) == set(PHASES)
+    for name in PHASES:
+        assert st2["phase_calls"][name] > 0, name
+        assert st2["phase_ns"][name] > 0, name
+    assert st2["phase_calls"]["serve.decode"] == st2["decode_steps"]
+    assert st2["phase_calls"]["serve.prefill_chunk"] == \
+        st2["prefill_chunks"]
 
 
 # ---------------------------------------------------------------- gateway

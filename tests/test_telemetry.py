@@ -16,6 +16,7 @@ from repro.fabric import (RegistryClient, RegistryService, RetryPolicy,
                           ServiceInstance, ServicePool)
 from repro.telemetry import metrics, trace
 from repro.telemetry.metrics import MetricsRegistry
+from repro.telemetry.phases import Phases
 
 from conftest import poll_until
 
@@ -91,6 +92,71 @@ def test_labels_and_snapshot_shape():
     assert snap["counters"]["hits{service=gen}"] == 2
     assert snap["counters"]["hits{service=ckpt}"] == 1
     assert set(snap) == {"counters", "gauges", "histograms"}
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+def phases(family_ns, family_calls, names):
+    return Phases({n: metrics.counter(family_ns, phase=n) for n in names},
+                  {n: metrics.counter(family_calls, phase=n) for n in names})
+
+
+def test_phases_accumulate_and_nest():
+    ph = phases("test.phases.nest.phase_ns", "test.phases.nest.phase_calls",
+                ("outer", "inner"))
+    for _ in range(2):
+        with ph("outer"):
+            with ph("inner"):
+                time.sleep(0.002)
+            with ph("inner"):
+                pass
+    assert ph.calls == {"outer": 2, "inner": 4}
+    assert ph.ns["outer"] >= ph.ns["inner"] >= 2 * 2_000_000
+    counters = metrics.snapshot()["counters"]
+    assert counters["test.phases.nest.phase_calls{phase=inner}"] == 4
+    assert counters["test.phases.nest.phase_calls{phase=outer}"] == 2
+    assert counters["test.phases.nest.phase_ns{phase=outer}"] == \
+        ph.ns["outer"]
+
+
+def test_phase_closes_when_its_body_raises():
+    ph = phases("test.phases.raise.phase_ns",
+                "test.phases.raise.phase_calls", ("p",))
+    with pytest.raises(ValueError):
+        with ph("p"):
+            time.sleep(0.001)
+            raise ValueError("boom")
+    assert ph.calls["p"] == 1 and ph.ns["p"] >= 1_000_000
+    with pytest.raises(KeyError):
+        ph("unknown")
+    assert metrics.snapshot()["counters"][
+        "test.phases.raise.phase_calls{phase=p}"] == 1
+
+
+def test_phase_lands_in_a_profiler_trace(tmp_path):
+    """On the profiler's clock: the phase is a host-plane event of a
+    trace captured while it ran."""
+    import jax
+    import jax.numpy as jnp
+    ph = phases("test.phases.trace.phase_ns",
+                "test.phases.trace.phase_calls", ("serve.probe",))
+    jnp.ones(4).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with ph("serve.probe"):
+            (jnp.arange(8) * 2).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))[-1]
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    found = [(plane.name, e.duration_ns) for plane in pd.planes
+             for line in plane.lines for e in line.events
+             if e.name == "serve.probe"]
+    assert len(found) == 1
+    plane, dur = found[0]
+    assert plane.startswith("/host:") and dur > 0
+    assert ph.calls["serve.probe"] == 1
 
 
 def test_fab_metrics_rpc_served_by_every_engine():
@@ -237,6 +303,10 @@ def test_retry_yields_one_connected_trace(traced, reg):
               msg="pool root span")
         root_span = [s for s in trace.export()["spans"]
                      if s["name"] == "pool.svc.work"][0]
+        # the server finishes its span after it has responded
+        _wait(lambda: sum(s["name"] == "rpc.work"
+                          for s in trace.spans_for(root_span["trace"])) >= 2,
+              msg="both server spans")
         spans = trace.spans_for(root_span["trace"])
         attempts = sorted((s for s in spans if s["name"] == "attempt.work"),
                           key=lambda s: s["tags"]["n"])
