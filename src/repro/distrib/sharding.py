@@ -40,6 +40,7 @@ DEFAULT_RULES: Rules = {
     "vocab": ("model",),
     "heads": ("model",),
     "kv_heads": ("model",),
+    "kv_heads_dim": ("model",),      # kv heads × head_dim, heads major
     "mlp": ("model",),
     "experts": ("model",),
     "inner": ("model",),

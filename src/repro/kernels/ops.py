@@ -66,8 +66,7 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     # ---- xla / cost path ----
     B, S, Hq, D = q.shape
     T = k.shape[1]
-    vector_offset = hasattr(q_offset, "ndim") and q_offset.ndim > 0
-    if vector_offset or (S <= 16 and T > 64):
+    if S <= 16 and T > 64:
         return _attention_decode(q, k, v, causal=causal, window=window,
                                  softcap=softcap, q_offset=q_offset,
                                  prefix_len=prefix_len)
@@ -93,6 +92,27 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     return _attention_chunked(q, k, v, causal=causal, window=window,
                               softcap=softcap, q_offset=q_offset,
                               prefix_len=prefix_len, kv_chunk=kv_chunk)
+
+
+def decode_attention(q, k, v, pos, layer, *, window: int = 0,
+                     softcap: float = 0.0, impl: str = "auto"):
+    """One query token per slot against the cache as stored. q: (B,Hq,D);
+    k, v: (L,B,T,Hkv·D), the stack of layers; pos: (B,) each slot's
+    position (its own K/V already written); layer: index into the stack.
+    Returns (B,Hq,D).  Every impl but the kernel's takes the xla path
+    (the oracle is ``ref.decode_attention_ref``)."""
+    impl = resolve_impl(impl)
+    if impl in ("pallas", "interpret"):
+        from .decode_attention import decode_attention as kernel
+        return kernel(q, k, v, pos, layer, window=window, softcap=softcap,
+                      interpret=(impl == "interpret"))
+    B, T, HD = k.shape[1:]
+    D = q.shape[-1]
+    kl = k[layer].reshape(B, T, HD // D, D)
+    vl = v[layer].reshape(B, T, HD // D, D)
+    return _attention_decode(q[:, None], kl, vl, causal=True, window=window,
+                             softcap=softcap, q_offset=pos,
+                             prefix_len=None)[:, 0]
 
 
 def _softcap(logits, softcap):

@@ -68,6 +68,27 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype)
 
 
+def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                         pos: jax.Array, *, window: int = 0,
+                         softcap: float = 0.0) -> jax.Array:
+    """One query token per slot against its cache row, as stored.
+
+    q: (B, Hq, D); k, v: (B, T, Hkv·D), kv heads side by side in the
+    minor dim; pos: (B,) each slot's query position.  Returns (B, Hq, D).
+    """
+    B, Hq, D = q.shape
+    T = k.shape[1]
+
+    def one(qb, kb, vb, pb):
+        kh = kb.reshape(1, T, -1, D)
+        vh = vb.reshape(1, T, -1, D)
+        return attention_ref(qb[None, None], kh, vh, causal=True,
+                             window=window, softcap=softcap,
+                             q_offset=pb)[0, 0]
+
+    return jax.vmap(one)(q, k, v, pos)
+
+
 # ---------------------------------------------------------------------------
 # Mamba2 SSD (state-space duality)
 # ---------------------------------------------------------------------------

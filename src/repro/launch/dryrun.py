@@ -266,7 +266,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, unroll_periods: int = 0,
     rules = cell_rules(shape)
     dp_all = tuple(dp_axes(mesh)) + ("model",)
     if overrides.get("flat_dp"):
-        rules.update({"heads": (), "kv_heads": (), "mlp": (), "vocab": (),
+        rules.update({"heads": (), "kv_heads": (), "kv_heads_dim": (),
+                      "mlp": (), "vocab": (),
                       "experts": (), "inner": (), "lru": (),
                       "ssm_heads": (), "embed": dp_all, "batch": dp_all})
     pg_dtype = overrides.get("param_gather")

@@ -4,9 +4,13 @@ Supports: GQA/MQA (kv repeat), RoPE (per-kind theta), sliding-window
 ("local" blocks), tanh logit soft-capping, qk RMS-norm, QKV biases,
 prefix-LM bidirectional masks, and cross-attention (enc-dec).
 
-KV caches are dicts ``{"k": (B,T,Hkv,D), "v": (B,T,Hkv,D)}``; decode
-updates them with a dynamic slice at ``pos``.  When the cache sequence
-dim is sharded (sequence-parallel decode), the softmax reductions in
+KV caches are dicts ``{"k": (B,T,Hkv·D), "v": (B,T,Hkv·D)}``, lane-dense:
+the kv heads lie side by side in the minor dim (a head_dim of 64 as the
+minor dim would pad to the 128-lane tile), and the xla paths split the
+heads inside their einsums.  Decode writes each slot's new row at its
+``pos`` with one scatter and reads the cache where it lies (the
+decode-attention kernel on TPU).  When the cache sequence dim is sharded
+(sequence-parallel decode), the softmax reductions in
 ``kernels.ops._attention_decode`` are plain jnp reductions over T, so
 GSPMD emits the 2-pass (max/sum) cross-shard reduction instead of
 gathering the cache.
@@ -107,11 +111,8 @@ def attn_prefill(cfg: ModelConfig, p: dict, x, *, kind: str = "attn",
     o = ops.attention(q, k, v, causal=True, window=window,
                       softcap=cfg.attn_softcap, prefix_len=prefix_len,
                       impl=impl)
-    pad = cache_len - S
-    cache = {
-        "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-        "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-    }
+    pad = ((0, 0), (0, cache_len - S), (0, 0))
+    cache = {"k": jnp.pad(_lanes(k), pad), "v": jnp.pad(_lanes(v), pad)}
     return _out(cfg, p, o), cache
 
 
@@ -131,41 +132,51 @@ def attn_prefill_chunk(cfg: ModelConfig, p: dict, x, cache: dict, offset, *,
     q = _project_q(cfg, p, x, positions, kind)
     k_new, v_new = _project_kv(cfg, p, x, positions, kind)
     k = jax.lax.dynamic_update_slice(
-        cache["k"], k_new.astype(cache["k"].dtype), (0, off, 0, 0))
+        cache["k"], _lanes(k_new).astype(cache["k"].dtype), (0, off, 0))
     v = jax.lax.dynamic_update_slice(
-        cache["v"], v_new.astype(cache["v"].dtype), (0, off, 0, 0))
+        cache["v"], _lanes(v_new).astype(cache["v"].dtype), (0, off, 0))
     window = cfg.window if kind == "local" else 0
-    o = ops.attention(q, k, v, causal=True, window=window,
-                      softcap=cfg.attn_softcap, q_offset=off,
+    o = ops.attention(q, _heads(k, cfg.hd), _heads(v, cfg.hd), causal=True,
+                      window=window, softcap=cfg.attn_softcap, q_offset=off,
                       prefix_len=prefix_len, impl="xla")
     return _out(cfg, p, o), {"k": k, "v": v}
 
 
 def attn_decode(cfg: ModelConfig, p: dict, x, cache: dict, pos, *,
-                kind: str = "attn", prefix_len=None) -> Tuple[jax.Array, dict]:
-    """One-token decode against the KV cache. x: (B,1,d); ``pos`` is a
-    scalar (lockstep decode) or a (B,) vector (continuous batching)."""
-    pos = jnp.asarray(pos)
-    positions = (jnp.full((1, 1), 0) + pos) if pos.ndim == 0 \
-        else pos[:, None]
-    q = _project_q(cfg, p, x, positions, kind)
-    k_new, v_new = _project_kv(cfg, p, x, positions, kind)
-    if pos.ndim == 0:
-        k = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), (0, pos, 0, 0))
-    else:
-        b_idx = jnp.arange(x.shape[0])
-        k = cache["k"].at[b_idx, pos].set(
-            k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[b_idx, pos].set(
-            v_new[:, 0].astype(cache["v"].dtype))
+                kind: str = "attn", layer=None,
+                impl: str = "auto") -> Tuple[jax.Array, dict]:
+    """One-token decode against the KV cache, in place. x: (B,1,d); ``pos``
+    (B,) is each slot's position.  ``cache`` holds one layer's k/v,
+    (B,T,Hkv·D), or with ``layer`` the whole stack, (L,B,T,Hkv·D): each
+    slot's new row is written at (layer, slot, pos), every slot included,
+    and attention reads the stack where it lies.  Returns (out, the
+    written cache)."""
+    B = x.shape[0]
+    q = _project_q(cfg, p, x, pos[:, None], kind)
+    k_new, v_new = _project_kv(cfg, p, x, pos[:, None], kind)
+    k, v = cache["k"], cache["v"]
+    at = layer
+    if layer is None:
+        k, v, at = k[None], v[None], 0
+    slots = jnp.arange(B)
+    k = k.at[at, slots, pos].set(_lanes(k_new)[:, 0].astype(k.dtype))
+    v = v.at[at, slots, pos].set(_lanes(v_new)[:, 0].astype(v.dtype))
     window = cfg.window if kind == "local" else 0
-    o = ops.attention(q, k, v, causal=True, window=window,
-                      softcap=cfg.attn_softcap, q_offset=pos,
-                      prefix_len=prefix_len, impl="xla")
-    return _out(cfg, p, o), {"k": k, "v": v}
+    o = ops.decode_attention(q[:, 0], k, v, pos, at, window=window,
+                             softcap=cfg.attn_softcap, impl=impl)
+    if layer is None:
+        k, v = k[0], v[0]
+    return _out(cfg, p, o[:, None]), {"k": k, "v": v}
+
+
+def _lanes(kv):
+    """(B,S,Hkv,D) → (B,S,Hkv·D), the cache's lane-dense layout."""
+    return kv.reshape(kv.shape[:2] + (-1,))
+
+
+def _heads(kv, head_dim: int):
+    """(B,T,Hkv·D) → (B,T,Hkv,D)."""
+    return kv.reshape(kv.shape[:2] + (-1, head_dim))
 
 
 # ---------------------------------------------------------------------------

@@ -89,13 +89,17 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
                 capacity_factor: Optional[float] = None,
                 memory_kv: Optional[dict] = None,
                 causal: bool = True,
-                inner_sharding=None):
+                inner_sharding=None, layer=None):
     """Apply one block. Returns (x, aux, new_cache).
 
     ``inner_sharding``: optional constraint on the post-norm activations —
     under sequence-parallel residuals this pins ONE gather point that both
     the attention and (parallel-block) MLP branches consume, instead of
-    letting GSPMD reshard per consumer."""
+    letting GSPMD reshard per consumer.
+
+    ``layer`` (decode only): ``cache`` is the stack of this block's
+    position in the period, and the block reads and writes its own layer
+    of it."""
     aux = {}
     new_cache = dict(cache) if cache is not None else None
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -121,7 +125,7 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
         else:
             kv = {"k": cache["k"], "v": cache["v"]}
             mix, kv = attn_mod.attn_decode(cfg, p["attn"], h, kv, pos,
-                                           kind=kind, prefix_len=prefix_len)
+                                           kind=kind, layer=layer, impl=impl)
             new_cache.update(kv)
     elif kind == "rglru":
         if mode == "chunk":
@@ -129,8 +133,9 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
                              "blocks (rglru carries no resumable prefill "
                              "state)")
         if mode == "decode":
-            mix, st = rglru_block.rglru_block_decode(cfg, p["rec"], h, cache)
-            new_cache.update(st)
+            mix, st = rglru_block.rglru_block_decode(
+                cfg, p["rec"], h, _layer_of(cache, layer))
+            new_cache.update(_into_layer(cache, st, layer))
         else:
             mix, st = rglru_block.rglru_block_apply(
                 cfg, p["rec"], h, impl=impl, want_cache=(mode == "prefill"))
@@ -142,8 +147,9 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
                              "blocks (ssd carries no resumable prefill "
                              "state)")
         if mode == "decode":
-            mix, st = ssd_block.ssd_block_decode(cfg, p["rec"], h, cache)
-            new_cache.update(st)
+            mix, st = ssd_block.ssd_block_decode(
+                cfg, p["rec"], h, _layer_of(cache, layer))
+            new_cache.update(_into_layer(cache, st, layer))
         else:
             mix, st = ssd_block.ssd_block_apply(
                 cfg, p["rec"], h, impl=impl, want_cache=(mode == "prefill"))
@@ -172,6 +178,22 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
                           dropless=dropless)
             x = x + y
     return x, aux, new_cache
+
+
+def _layer_of(cache, layer):
+    """One layer of a stacked recurrent state (the state itself when
+    ``layer`` is None)."""
+    if layer is None:
+        return cache
+    return {n: a[layer] for n, a in cache.items()}
+
+
+def _into_layer(cache, state, layer):
+    """``state`` written back at ``layer`` of the stacked ``cache``."""
+    if layer is None:
+        return state
+    return {n: jax.lax.dynamic_update_index_in_dim(
+        cache[n], state[n].astype(cache[n].dtype), layer, 0) for n in state}
 
 
 # ===========================================================================
@@ -420,18 +442,28 @@ class Model:
 
     def decode_step(self, params, cache, tokens, pos, *, spmd=None,
                     impl: str = "auto"):
-        """One token for every sequence. tokens: (B,1); pos: scalar int32.
-        Returns (logits (B,V), new cache)."""
+        """One token for every sequence. tokens: (B,1); pos: a scalar int32
+        or (B,), each slot's position.  Returns (logits (B,V), new cache).
+
+        The layer scan carries the stacked cache and hands each block its
+        layer index: each attention layer writes its new K/V rows at
+        (layer, slot, pos) and reads the stack where it lies, so under a
+        jit that donates the cache the step updates it in place.
+        Cross-attention K/V pass through unwritten."""
         cfg = self.cfg
         h = embed_tokens(cfg, params["embed"], tokens)
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1])
 
-        def apply_one(h, p, kind, c):
+        def apply_one(h, p, kind, c, layer=None):
             mkv = None
-            if c is not None and "cross_k" in c:
+            if "cross_k" in c:
                 mkv = {"k": c["cross_k"], "v": c["cross_v"]}
+                if layer is not None:
+                    mkv = {n: a[layer] for n, a in mkv.items()}
             h, _, nc = block_apply(cfg, p, h, kind, mode="decode", cache=c,
                                    pos=pos, spmd=spmd, impl=impl,
-                                   capacity_factor=None, memory_kv=mkv)
+                                   capacity_factor=None, memory_kv=mkv,
+                                   layer=layer)
             return h, nc
 
         new_cache: Dict[str, Any] = {}
@@ -443,18 +475,21 @@ class Model:
 
         plen = len(cfg.period)
 
-        def period_body(h, xs):
-            layer_p, layer_c = xs
-            ncs = []
+        def period_body(carry, xs):
+            h, stacks = carry
+            layer_p, layer = xs
+            stacks = list(stacks)
             for posn in range(plen):
-                h, nc = apply_one(h, layer_p[posn], cfg.period[posn],
-                                  layer_c[posn])
-                ncs.append(nc)
-            return h, tuple(ncs)
+                h, stacks[posn] = apply_one(h, layer_p[posn],
+                                            cfg.period[posn], stacks[posn],
+                                            layer)
+            return (h, tuple(stacks)), None
 
         if self.n_scan_periods:
-            h, new_cache["periods"] = jax.lax.scan(
-                period_body, h, (params["periods"], cache["periods"]))
+            (h, new_cache["periods"]), _ = jax.lax.scan(
+                period_body, (h, cache["periods"]),
+                (params["periods"],
+                 jnp.arange(self.n_scan_periods, dtype=jnp.int32)))
         else:
             new_cache["periods"] = ()
 
@@ -544,14 +579,11 @@ class Model:
 
         def one(kind):
             if kind in ATTN_KINDS:
-                c = {
-                    "k": P(jnp.zeros((batch_size, cache_len, cfg.n_kv_heads,
-                                      cfg.hd), dtype),
-                           ("batch", "kv_seq", "kv_heads", "head_dim")),
-                    "v": P(jnp.zeros((batch_size, cache_len, cfg.n_kv_heads,
-                                      cfg.hd), dtype),
-                           ("batch", "kv_seq", "kv_heads", "head_dim")),
-                }
+                # lane-dense: the kv heads side by side in the minor dim
+                shape = (batch_size, cache_len, cfg.n_kv_heads * cfg.hd)
+                axes = ("batch", "kv_seq", "kv_heads_dim")
+                c = {"k": P(jnp.zeros(shape, dtype), axes),
+                     "v": P(jnp.zeros(shape, dtype), axes)}
             elif kind == "rglru":
                 s = rglru_block.rglru_cache_spec(cfg, batch_size, dtype)
                 c = {"h": P(s["h"], ("batch", "lru")),

@@ -130,6 +130,24 @@ class Request:
                 pass       # a failing waiter must not kill the step loop
 
 
+def donates(device=None) -> bool:
+    """Whether the engine's steps update the batched cache in place.  On
+    an accelerator the cache is most of the device's memory, and a step
+    that kept its input would hold it twice.  The CPU backend keeps the
+    caller's arrays, so a wrapper of a step may still hand its input
+    back."""
+    return (device or jax.devices()[0]).platform != "cpu"
+
+
+def decode_executable(model: Model, impl: str, *, donate: bool):
+    """The engine's decode step, jitted as ``jit_decode_step`` (the name
+    the device trace finds it under).  ``donate``: the cache (argument 1)
+    is consumed and the step writes its new K/V rows into it in place."""
+    def decode_step(p, c, t, pos):
+        return model.decode_step(p, c, t, pos, impl=impl)
+    return jax.jit(decode_step, donate_argnums=(1,) if donate else ())
+
+
 class ServeEngine:
     """``device`` pins the engine: parameters, the batched cache, the B=1
     staging cache and every jitted output live on that one device, so
@@ -188,14 +206,14 @@ class ServeEngine:
         def prefill(p, b):
             return model.prefill(p, b, cache_len=max_len, impl=impl)
 
-        def decode_step(p, c, t, pos):
-            return model.decode_step(p, c, t, pos, impl=impl)
-
         def prefill_chunk(p, c, t, off):
             return model.prefill_chunk(p, c, t, off, impl=impl)
 
+        # the batched cache is updated in place where the backend donates:
+        # every step that takes it (decode, slot scatter) consumes it
+        donate = donates(device)
         self._prefill_jit = jax.jit(prefill)
-        self._decode_jit = jax.jit(decode_step)
+        self._decode_jit = decode_executable(model, impl, donate=donate)
         if self.chunk or self.session_cap:
             self._chunk_jit = jax.jit(prefill_chunk)
             # zeroed B=1 staging cache, shared template for fresh prompts
@@ -225,7 +243,8 @@ class ServeEngine:
                 and not isinstance(x, dict))
 
         self._gather_jit = jax.jit(gather_slot)
-        self._scatter_jit = jax.jit(scatter_slot)
+        self._scatter_jit = jax.jit(scatter_slot,
+                                    donate_argnums=(0,) if donate else ())
 
     # -------------------------------------------------------------- placement
     def _put(self, x):
@@ -530,7 +549,8 @@ class ServeEngine:
         """Fail every request the engine holds — decoding, mid-prefill,
         pending or queued — with ``reason``, and drop the pinned
         sessions (a step that raised may have left any slot's KV half
-        written).  Step-thread only.  Returns how many were failed."""
+        written, or the whole cache donated).  Step-thread only.  Returns
+        how many were failed."""
         reqs = [r for r in self.slot_req if r is not None]
         reqs += list(self._pending)
         while True:
@@ -540,6 +560,10 @@ class ServeEngine:
                 break
         self._pending.clear()
         self._prefill.clear()
+        # a step that raised after its dispatch donated the cache left it
+        # deleted: start again from a zeroed one
+        if any(x.is_deleted() for x in jax.tree_util.tree_leaves(self.cache)):
+            self.cache, _ = self._zero_cache(self.n_slots)
         self.slot_req = [None] * self.n_slots
         self.slot_session = [None] * self.n_slots
         self.sessions.clear()

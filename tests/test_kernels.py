@@ -80,6 +80,39 @@ def test_attention_decode_property(rng):
     np.testing.assert_allclose(got[:, 0], full[:, -1], rtol=2e-5, atol=2e-5)
 
 
+DECODE_SWEEP = [
+    # Hq, Hkv, D, window, softcap, dtype
+    (4, 4, 32, 0, 0.0, "float32"),          # GQA repeat 1
+    (8, 4, 16, 0, 0.0, "float32"),          # repeat 2
+    (16, 2, 32, 0, 0.0, "float32"),         # repeat 8
+    (8, 4, 16, 200, 0.0, "float32"),        # sliding window
+    (4, 2, 32, 0, 30.0, "float32"),         # soft-capped logits
+    (16, 2, 64, 300, 50.0, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("case", DECODE_SWEEP)
+def test_decode_attention_vs_ref(case):
+    """The decode kernel (interpret mode) and the xla path read a stacked
+    lane-dense cache at one layer; per-slot positions at the first
+    position, either side of a block edge, and the cache's last."""
+    Hq, Hkv, D, window, softcap, dt = case
+    L, T, layer = 3, 512, 2
+    pos = jnp.asarray([0, 127, 128, T - 1], jnp.int32)
+    B = pos.shape[0]
+    q = t(B, Hq, D, dtype=dt)
+    k, v = t(L, B, T, Hkv * D, dtype=dt), t(L, B, T, Hkv * D, dtype=dt)
+    want = ref.decode_attention_ref(q, k[layer], v[layer], pos,
+                                    window=window, softcap=softcap)
+    tol = 2e-2 if dt == "bfloat16" else 2e-5
+    for impl in ("interpret", "xla"):
+        got = ops.decode_attention(q, k, v, pos, layer, window=window,
+                                   softcap=softcap, impl=impl)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol, err_msg=impl)
+
+
 SSD_SWEEP = [
     (2, 64, 4, 8, 2, 16, 32, True, True),
     (1, 100, 2, 16, 1, 8, 32, False, False),

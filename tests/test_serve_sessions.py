@@ -11,6 +11,7 @@ from repro import configs
 from repro.fabric import SessionAffinity
 from repro.fabric.balancer import prefer_instance
 from repro.models import Model, unzip
+from repro.serve import engine as engine_mod
 from repro.serve.engine import PHASES, ServeEngine
 from repro.services import ServingGateway
 
@@ -247,6 +248,64 @@ def test_work_counters_and_phases(model_and_params):
     assert st2["phase_calls"]["serve.decode"] == st2["decode_steps"]
     assert st2["phase_calls"]["serve.prefill_chunk"] == \
         st2["prefill_chunks"]
+
+
+# -------------------------------------------------------------- donation
+@pytest.fixture
+def donating(monkeypatch):
+    """Engines built under it donate the batched cache, as they do on a
+    chip."""
+    monkeypatch.setattr(engine_mod, "donates", lambda device=None: True)
+
+
+def _deleted(tree):
+    return [x.is_deleted() for x in jax.tree_util.tree_leaves(tree)]
+
+
+def test_step_consumes_the_donated_cache(model_and_params, donating):
+    """A step's slot scatter and decode update the cache in place: the
+    cache leaves held before the step are deleted after it, and the
+    tokens are those of an engine that keeps its inputs."""
+    m, params = model_and_params
+    prompt = np.arange(1, 7)
+    eng = make_engine(m, params, n_slots=2, chunk_tokens=8)
+    req = eng.submit(prompt, max_new=6)
+    before = eng.cache
+    eng.step()                       # the prompt's one chunk, then decode
+    assert all(_deleted(before))
+    assert not any(_deleted(eng.cache))
+    eng.drain()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "donates", lambda device=None: False)
+        keeps = make_engine(m, params, n_slots=2, chunk_tokens=8)
+    assert req.out_tokens == keeps.generate([prompt], max_new=6)[0]
+
+
+def test_fail_all_recovers_a_donated_cache(model_and_params, donating):
+    """A decode that raised after its dispatch consumed the cache leaves
+    ``self.cache`` deleted; ``fail_all`` fails the request and zeroes the
+    cache, and the next request is served as a fresh engine serves it."""
+    m, params = model_and_params
+    eng = make_engine(m, params, n_slots=2, chunk_tokens=8, session_cap=2)
+    decode = eng._decode_jit
+
+    def lost(p, c, t, pos):
+        decode(p, c, t, pos)
+        raise RuntimeError("device lost mid-step")
+    eng._decode_jit = lost
+    req = eng.submit(np.arange(1, 7), max_new=5, session_id="s")
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.step()
+    assert any(_deleted(eng.cache))
+    assert eng.fail_all("step failed") == 1
+    assert req.error == "step failed" and not any(_deleted(eng.cache))
+
+    eng._decode_jit = decode
+    prompt = np.arange(3, 17)
+    got = eng.generate([prompt], max_new=6)[0]
+    want = make_engine(m, params, n_slots=2, chunk_tokens=8,
+                       session_cap=2).generate([prompt], max_new=6)[0]
+    assert got == want
 
 
 # ---------------------------------------------------------------- gateway

@@ -8,18 +8,22 @@ runs; the tests say nothing about results or times.  Kernels get shapes
 at the published widths of the model that uses them.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_router import router_topk_pallas
 from repro.kernels.rglru_scan import rglru_pallas
 from repro.kernels.ssd import ssd_pallas
 from repro.models import Model, unzip
+from repro.serve.engine import decode_executable
 
 HBM_BYTES = 16 * 2 ** 30        # one v5e chip
 
@@ -88,17 +92,54 @@ def test_qwen_prefill_holds_flash_kernel(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_qwen_decode_step_fits_one_chip(one_chip):
-    model, params, cache = _qwen_shapes(one_chip, 8, 1024)
-    toks = _sds(one_chip, (8, 1), jnp.int32)
-    pos = _sds(one_chip, (8,), jnp.int32)
-    c = _compile(lambda p, c, t, q: model.decode_step(p, c, t, q,
-                                                      impl="pallas"),
-                 params, cache, toks, pos)
+def test_decode_attention_qwen_width(one_chip):
+    cfg = configs.get("qwen1.5-0.5b")
+    B, T = 24, 2048
+    q = _sds(one_chip, (B, cfg.n_heads, cfg.hd))
+    kv = _sds(one_chip, (cfg.n_layers, B, T, cfg.n_kv_heads * cfg.hd))
+    c = _compile(lambda q, k, v, p, i: decode_attention(q, k, v, p, i),
+                 q, kv, kv, _sds(one_chip, (B,), jnp.int32),
+                 _sds(one_chip, (), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def _copy_bytes(hlo: str):
+    """Bytes of each ``copy`` (or async ``copy-start``) result in an HLO
+    text."""
+    width = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+             "u8": 1, "pred": 1}
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]*)\]\S* copy(?:-start)?\(",
+                         hlo):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        yield width.get(m.group(1), 4) * int(np.prod(dims))
+
+
+@pytest.mark.parametrize("batch,cache_len,budget", [
+    (8, 1024, HBM_BYTES),
+    (24, 2048, 8.2e9),          # the benchmark's short_decode deployment
+])
+def test_qwen_decode_step_fits_one_chip(one_chip, batch, cache_len, budget):
+    """The engine's own decode executable, as it builds it on a chip
+    (kernel path, cache donated), updates the bfloat16 cache in place:
+    the output aliases the whole cache, no copy of one layer's K or V or
+    more is made, and what the step holds fits the budget."""
+    model, params, cache = _qwen_shapes(one_chip, batch, cache_len)
+    toks = _sds(one_chip, (batch, 1), jnp.int32)
+    pos = _sds(one_chip, (batch,), jnp.int32)
+    c = decode_executable(model, "pallas", donate=True).lower(
+        params, cache, toks, pos).compile()
     m = c.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes)
-    assert total < HBM_BYTES, total
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= cache_bytes, (m.alias_size_in_bytes,
+                                                  cache_bytes)
+    k = cache["periods"][0]["k"]
+    layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
+    big = [b for b in _copy_bytes(c.as_text()) if b >= layer_bytes]
+    assert not big, big
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held <= budget, held
 
 
 def test_router_topk_granite_width(one_chip):
