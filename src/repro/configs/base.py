@@ -78,6 +78,12 @@ class ModelConfig:
     mlp: str = "swiglu"             # swiglu | geglu | relu2 | gelu
     tie_embeddings: bool = False
     embed_scale: bool = False       # gemma-style sqrt(d_model) embed scaling
+    # granite-style multipliers, named as in the source config; 1 (and 0
+    # for the attention scale) is no operation
+    embedding_multiplier: float = 1.0   # embeddings × this
+    attention_multiplier: float = 0.0   # q·k scale (0 -> 1/sqrt(head_dim))
+    residual_multiplier: float = 1.0    # each branch × this before its add
+    logits_scaling: float = 1.0         # final logits ÷ this
     # families
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
@@ -97,6 +103,12 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """The q·k scale when the config sets one; None leaves the
+        attention paths at their 1/sqrt(head_dim)."""
+        return self.attention_multiplier or None
 
     @property
     def n_periods(self) -> int:

@@ -20,6 +20,7 @@ head's own.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -114,11 +115,13 @@ def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 
 
 def decode_attention(q, k, v, pos, layer, *, window: int = 0,
-                     softcap: float = 0.0, interpret: bool = False):
+                     softcap: float = 0.0,
+                     scale: Optional[float] = None,
+                     interpret: bool = False):
     """q: (B,Hq,D); k, v: (L,B,T,Hkv·D) as stored; pos: (B,) int32, each
     slot's query position (its own K/V already written there); layer: the
-    index into the stack → (B,Hq,D).  A T that ``BLOCK_K`` does not
-    divide is one block."""
+    index into the stack; ``scale`` multiplies q·k (None: 1/sqrt(D)) →
+    (B,Hq,D).  A T that ``BLOCK_K`` does not divide is one block."""
     B, Hq, D = q.shape
     T, HD = k.shape[2], k.shape[3]
     Hkv = HD // D
@@ -132,7 +135,8 @@ def decode_attention(q, k, v, pos, layer, *, window: int = 0,
     qbd = jnp.where(own, q.reshape(B, Hkv, rep, 1, D), 0).reshape(B, Hq, HD)
 
     kernel = functools.partial(_kernel, window=window, softcap=softcap,
-                               scale=1.0 / np.sqrt(D), block_k=bk, nk=nk)
+                               scale=1.0 / np.sqrt(D) if scale is None
+                               else scale, block_k=bk, nk=nk)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

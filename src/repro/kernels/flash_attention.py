@@ -96,8 +96,10 @@ def _kernel(prefix_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, q_offset: int = 0,
                     prefix_len=None, interpret: bool = False,
-                    block_q: int = 128, block_k: int = 128):
-    """q: (B,S,Hq,D); k,v: (B,T,Hkv,D) → (B,S,Hq,D).
+                    block_q: int = 128, block_k: int = 128,
+                    scale: Optional[float] = None):
+    """q: (B,S,Hq,D); k,v: (B,T,Hkv,D) → (B,S,Hq,D).  ``scale``
+    multiplies q·k (None: 1/sqrt(D)).
 
     ``q_offset`` must be 0 for the kernel path (decode uses the xla path).
     """
@@ -128,7 +130,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(
         _kernel, causal=causal, window=window, softcap=softcap,
-        scale=1.0 / np.sqrt(D), nk=nk, block_q=bq, block_k=bk,
+        scale=1.0 / np.sqrt(D) if scale is None else scale, nk=nk, block_q=bq, block_k=bk,
         t_real=T, use_prefix=use_prefix)
 
     out = pl.pallas_call(

@@ -44,8 +44,10 @@ def resolve_impl(impl: str) -> str:
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               softcap: float = 0.0, q_offset: int = 0,
               prefix_len=None, impl: str = "auto",
-              kv_chunk: int = 512, q_block: int = 512):
+              kv_chunk: int = 512, q_block: int = 512,
+              scale: Optional[float] = None):
     """Multi-head GQA attention. q: (B,S,Hq,D); k,v: (B,T,Hkv,D).
+    ``scale`` multiplies q·k (None: 1/sqrt(D)).
 
     Shape-driven strategy for the xla path:
       * decode (S small, T large)          → masked full-logit matvec
@@ -56,12 +58,12 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     if impl == "ref":
         return ref_mod.attention_ref(q, k, v, causal=causal, window=window,
                                      softcap=softcap, q_offset=q_offset,
-                                     prefix_len=prefix_len)
+                                     prefix_len=prefix_len, scale=scale)
     if impl in ("pallas", "interpret"):
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset,
-                               prefix_len=prefix_len,
+                               prefix_len=prefix_len, scale=scale,
                                interpret=(impl == "interpret"))
     # ---- xla / cost path ----
     B, S, Hq, D = q.shape
@@ -69,50 +71,53 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     if S <= 16 and T > 64:
         return _attention_decode(q, k, v, causal=causal, window=window,
                                  softcap=softcap, q_offset=q_offset,
-                                 prefix_len=prefix_len)
+                                 prefix_len=prefix_len, scale=scale)
     if impl == "cost":
         # scan-free: naive einsum attention has the same matmul FLOPs as
         # the chunked/flash path (masking does not reduce einsum FLOPs)
         if causal and window > 0 and S == T and prefix_len is None \
                 and S >= 2 * window and S % window == 0:
             return _attention_local_blocked(q, k, v, window=window,
-                                            softcap=softcap)
+                                            softcap=softcap, scale=scale)
         return ref_mod.attention_ref(q, k, v, causal=causal, window=window,
                                      softcap=softcap, q_offset=q_offset,
-                                     prefix_len=prefix_len)
+                                     prefix_len=prefix_len, scale=scale)
     if (causal and window > 0 and S == T and prefix_len is None
             and S >= 2 * window and S % window == 0):
         return _attention_local_blocked(q, k, v, window=window,
-                                        softcap=softcap)
+                                        softcap=softcap, scale=scale)
     # naive path only when the full logits tensor is demonstrably small
     if B * Hq * S * T * 4 <= (64 << 20):
         return ref_mod.attention_ref(q, k, v, causal=causal, window=window,
                                      softcap=softcap, q_offset=q_offset,
-                                     prefix_len=prefix_len)
+                                     prefix_len=prefix_len, scale=scale)
     return _attention_chunked(q, k, v, causal=causal, window=window,
                               softcap=softcap, q_offset=q_offset,
-                              prefix_len=prefix_len, kv_chunk=kv_chunk)
+                              prefix_len=prefix_len, kv_chunk=kv_chunk,
+                              scale=scale)
 
 
 def decode_attention(q, k, v, pos, layer, *, window: int = 0,
-                     softcap: float = 0.0, impl: str = "auto"):
+                     softcap: float = 0.0, scale: Optional[float] = None,
+                     impl: str = "auto"):
     """One query token per slot against the cache as stored. q: (B,Hq,D);
     k, v: (L,B,T,Hkv·D), the stack of layers; pos: (B,) each slot's
-    position (its own K/V already written); layer: index into the stack.
-    Returns (B,Hq,D).  Every impl but the kernel's takes the xla path
-    (the oracle is ``ref.decode_attention_ref``)."""
+    position (its own K/V already written); layer: index into the stack;
+    ``scale`` multiplies q·k (None: 1/sqrt(D)).  Returns (B,Hq,D).
+    Every impl but the kernel's takes the xla path (the oracle is
+    ``ref.decode_attention_ref``)."""
     impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         from .decode_attention import decode_attention as kernel
         return kernel(q, k, v, pos, layer, window=window, softcap=softcap,
-                      interpret=(impl == "interpret"))
+                      scale=scale, interpret=(impl == "interpret"))
     B, T, HD = k.shape[1:]
     D = q.shape[-1]
     kl = k[layer].reshape(B, T, HD // D, D)
     vl = v[layer].reshape(B, T, HD // D, D)
     return _attention_decode(q[:, None], kl, vl, causal=True, window=window,
                              softcap=softcap, q_offset=pos,
-                             prefix_len=None)[:, 0]
+                             prefix_len=None, scale=scale)[:, 0]
 
 
 def _softcap(logits, softcap):
@@ -121,8 +126,15 @@ def _softcap(logits, softcap):
     return logits
 
 
+def _scaled(logits, D, scale):
+    """q·k logits at ``scale`` (None: divided by sqrt(D))."""
+    if scale is None:
+        return logits / np.sqrt(D)
+    return logits * scale
+
+
 def _attention_decode(q, k, v, *, causal, window, softcap, q_offset,
-                      prefix_len):
+                      prefix_len, scale=None):
     """Small-S (decode) attention: full logits over T, masked softmax.
     Written as plain jnp reductions over T so that GSPMD shards T (the KV
     sequence) and emits the 2-pass (max, sum) all-reduces itself.
@@ -135,7 +147,7 @@ def _attention_decode(q, k, v, *, causal, window, softcap, q_offset,
     qf = q.astype(jnp.float32).reshape(B, S, Hkv, rep, D)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    logits = jnp.einsum("bsgrd,btgd->bgrst", qf, kf) / np.sqrt(D)
+    logits = _scaled(jnp.einsum("bsgrd,btgd->bgrst", qf, kf), D, scale)
     logits = _softcap(logits, softcap)
     qoff = jnp.asarray(q_offset)
     if qoff.ndim == 0:
@@ -157,7 +169,7 @@ def _attention_decode(q, k, v, *, causal, window, softcap, q_offset,
     return out.reshape(B, S, Hq, D).astype(q.dtype)
 
 
-def _attention_local_blocked(q, k, v, *, window, softcap):
+def _attention_local_blocked(q, k, v, *, window, softcap, scale=None):
     """Exact sliding-window attention in O(S·2W): queries in blocks of W
     attend to their own and the previous key block."""
     B, S, Hq, D = q.shape
@@ -172,7 +184,7 @@ def _attention_local_blocked(q, k, v, *, window, softcap):
     v_prev = jnp.pad(vf[:, :-1], ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))
     k2 = jnp.concatenate([k_prev, kf], axis=2)   # (B,nb,2W,H,D)
     v2 = jnp.concatenate([v_prev, vf], axis=2)
-    logits = jnp.einsum("bnqhd,bnkhd->bnhqk", qf, k2) / np.sqrt(D)
+    logits = _scaled(jnp.einsum("bnqhd,bnkhd->bnhqk", qf, k2), D, scale)
     logits = _softcap(logits, softcap)
     qpos = jnp.arange(W)[:, None] + W                 # position within 2W frame
     kpos = jnp.arange(2 * W)[None, :]
@@ -187,7 +199,7 @@ def _attention_local_blocked(q, k, v, *, window, softcap):
 
 
 def _attention_chunked(q, k, v, *, causal, window, softcap, q_offset,
-                       prefix_len, kv_chunk):
+                       prefix_len, kv_chunk, scale=None):
     """Online-softmax flash attention as a lax.scan over KV chunks —
     O(S·Ck) live memory, exact."""
     B, S, Hq, D = q.shape
@@ -209,7 +221,7 @@ def _attention_chunked(q, k, v, *, causal, window, softcap, q_offset,
         m_prev, l_prev, acc = carry
         kc, vc, c_idx = inp
         kpos = jnp.arange(Ck)[None, :] + c_idx * Ck
-        logits = jnp.einsum("bsgrd,bkgd->bsgrk", qf, kc) / np.sqrt(D)
+        logits = _scaled(jnp.einsum("bsgrd,bkgd->bsgrk", qf, kc), D, scale)
         logits = _softcap(logits, softcap)
         mask = kpos < T
         if causal:

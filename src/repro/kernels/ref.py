@@ -70,7 +70,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                          pos: jax.Array, *, window: int = 0,
-                         softcap: float = 0.0) -> jax.Array:
+                         softcap: float = 0.0,
+                         scale: Optional[float] = None) -> jax.Array:
     """One query token per slot against its cache row, as stored.
 
     q: (B, Hq, D); k, v: (B, T, Hkv·D), kv heads side by side in the
@@ -84,7 +85,7 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
         vh = vb.reshape(1, T, -1, D)
         return attention_ref(qb[None, None], kh, vh, causal=True,
                              window=window, softcap=softcap,
-                             q_offset=pb)[0, 0]
+                             q_offset=pb, scale=scale)[0, 0]
 
     return jax.vmap(one)(q, k, v, pos)
 

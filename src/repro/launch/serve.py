@@ -9,6 +9,8 @@ Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced --demo
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
       --listen tcp://0.0.0.0:7777        # stay up as a server
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-moe-3b-a800m \
+      --param-dtype bfloat16 --slots 24 --max-len 2048   # one v5e chip
 """
 from __future__ import annotations
 
@@ -34,6 +36,11 @@ def main(argv=None):
     ap.add_argument("--listen", default="tcp://127.0.0.1:0")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
+    ap.add_argument("--param-dtype", default=None,
+                    choices=("float32", "bfloat16"),
+                    help="dtype the weights are held in (default: the "
+                         "preset's; bfloat16 halves them, as a full-size "
+                         "granite-moe-3b-a800m needs on one chip)")
     ap.add_argument("--demo", action="store_true")
     ap.add_argument("--registry", default=None, metavar="URI[,URI...]",
                     help="fabric registry to self-register with (service "
@@ -65,6 +72,8 @@ def main(argv=None):
         trace.configure(sample=args.trace_sample)
 
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
+    if args.param_dtype:
+        cfg = cfg.replace(param_dtype=args.param_dtype)
     model = Model(cfg)
     params, _ = unzip(model.init(jax.random.PRNGKey(0)))
     serve = ServeEngine(model, params, max_len=args.max_len,

@@ -94,8 +94,8 @@ def attn_apply(cfg: ModelConfig, p: dict, x, *, kind: str = "attn",
     k, v = _project_kv(cfg, p, x, positions, kind)
     window = cfg.window if kind == "local" else 0
     o = ops.attention(q, k, v, causal=causal, window=window,
-                      softcap=cfg.attn_softcap, prefix_len=prefix_len,
-                      impl=impl)
+                      softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+                      prefix_len=prefix_len, impl=impl)
     return _out(cfg, p, o)
 
 
@@ -109,8 +109,8 @@ def attn_prefill(cfg: ModelConfig, p: dict, x, *, kind: str = "attn",
     k, v = _project_kv(cfg, p, x, positions, kind)
     window = cfg.window if kind == "local" else 0
     o = ops.attention(q, k, v, causal=True, window=window,
-                      softcap=cfg.attn_softcap, prefix_len=prefix_len,
-                      impl=impl)
+                      softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+                      prefix_len=prefix_len, impl=impl)
     pad = ((0, 0), (0, cache_len - S), (0, 0))
     cache = {"k": jnp.pad(_lanes(k), pad), "v": jnp.pad(_lanes(v), pad)}
     return _out(cfg, p, o), cache
@@ -137,7 +137,8 @@ def attn_prefill_chunk(cfg: ModelConfig, p: dict, x, cache: dict, offset, *,
         cache["v"], _lanes(v_new).astype(cache["v"].dtype), (0, off, 0))
     window = cfg.window if kind == "local" else 0
     o = ops.attention(q, _heads(k, cfg.hd), _heads(v, cfg.hd), causal=True,
-                      window=window, softcap=cfg.attn_softcap, q_offset=off,
+                      window=window, softcap=cfg.attn_softcap,
+                      scale=cfg.attn_scale, q_offset=off,
                       prefix_len=prefix_len, impl="xla")
     return _out(cfg, p, o), {"k": k, "v": v}
 
@@ -163,7 +164,8 @@ def attn_decode(cfg: ModelConfig, p: dict, x, cache: dict, pos, *,
     v = v.at[at, slots, pos].set(_lanes(v_new)[:, 0].astype(v.dtype))
     window = cfg.window if kind == "local" else 0
     o = ops.decode_attention(q[:, 0], k, v, pos, at, window=window,
-                             softcap=cfg.attn_softcap, impl=impl)
+                             softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+                             impl=impl)
     if layer is None:
         k, v = k[0], v[0]
     return _out(cfg, p, o[:, None]), {"k": k, "v": v}
@@ -190,7 +192,8 @@ def cross_attn_apply(cfg: ModelConfig, p: dict, x, memory_kv: dict,
     positions = jnp.zeros((1, S), jnp.int32)
     q = _project_q(cfg, p, x, positions, kind="attn", use_rope=False)
     o = ops.attention(q, memory_kv["k"], memory_kv["v"], causal=False,
-                      softcap=cfg.attn_softcap, impl=impl)
+                      softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+                      impl=impl)
     return _out(cfg, p, o)
 
 

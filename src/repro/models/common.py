@@ -189,6 +189,8 @@ def embed_tokens(cfg: ModelConfig, p: dict, tokens):
     h = jnp.take(p["embedding"], tokens, axis=0).astype(cdt)
     if cfg.embed_scale:
         h = h * jnp.asarray(math.sqrt(cfg.d_model), cdt)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, cdt)
     return h
 
 
@@ -197,6 +199,8 @@ def unembed(cfg: ModelConfig, p: dict, h):
     w = p["embedding"].T if cfg.tie_embeddings else p["head"]
     logits = h.astype(cdt) @ w.astype(cdt)
     logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap > 0.0:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     return logits

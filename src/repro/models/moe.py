@@ -57,6 +57,20 @@ def padded_experts(cfg: ModelConfig, n_shards: int) -> int:
     return int(math.ceil(e / n_shards) * n_shards)
 
 
+def capacity(cfg: ModelConfig, tokens: int, capacity_factor: float,
+             dropless: bool) -> int:
+    """Rows per expert of the dispatch buffer for ``tokens`` local
+    tokens.  Dropless: every expert can hold every assignment it could
+    receive (each token contributes at most one assignment per expert) —
+    used for serving, where per-step dropping would make decode diverge
+    from prefill."""
+    if dropless:
+        return tokens
+    return max(int(math.ceil(tokens * cfg.moe.top_k
+                             / max(cfg.moe.num_experts, 1)
+                             * capacity_factor)), 1)
+
+
 def moe_params(cfg: ModelConfig, rng, path, e_pad: Optional[int] = None) -> dict:
     dt = jnp.dtype(cfg.param_dtype)
     d, f = cfg.d_model, cfg.d_ff
@@ -104,64 +118,62 @@ def _moe_local(cfg: ModelConfig, params: dict, x2d, *, e_start, e_local,
     T, d = x2d.shape
     E_real, k = cfg.moe.num_experts, cfg.moe.top_k
     cdt = jnp.dtype(cfg.compute_dtype)
+    C = capacity(cfg, T, capacity_factor, dropless)
 
-    logits = x2d.astype(cdt) @ params["router"].astype(cdt)      # (T, E_pad)
-    logits = logits.astype(jnp.float32)
-    if e_pad > E_real:
-        pad_mask = jnp.arange(e_pad) >= E_real
-        logits = jnp.where(pad_mask[None], -1e30, logits)
-    w, idx, probs = ops.router_topk(logits, k, impl=router_impl)  # (T,k)
+    with jax.named_scope("moe.route"):
+        logits = x2d.astype(cdt) @ params["router"].astype(cdt)  # (T, E_pad)
+        logits = logits.astype(jnp.float32)
+        if e_pad > E_real:
+            pad_mask = jnp.arange(e_pad) >= E_real
+            logits = jnp.where(pad_mask[None], -1e30, logits)
+        w, idx, probs = ops.router_topk(logits, k,
+                                        impl=router_impl)       # (T,k)
 
-    # aux stats (sums; caller normalizes / psums): load per expert,
-    # mean prob per expert, router z
-    assign_oh = jax.nn.one_hot(idx, e_pad, dtype=jnp.float32).sum(1)  # (T,E)
-    load_sum = assign_oh.sum(0)                                   # (E,)
-    prob_sum = probs.sum(0)                                       # (E,)
-    z_sum = jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum()
+        # aux stats (sums; caller normalizes / psums): load per expert,
+        # mean prob per expert, router z
+        assign_oh = jax.nn.one_hot(idx, e_pad, dtype=jnp.float32).sum(1)
+        load_sum = assign_oh.sum(0)                               # (E,)
+        prob_sum = probs.sum(0)                                   # (E,)
+        z_sum = jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum()
 
-    if dropless:
-        # every expert can hold every assignment it could receive (each
-        # token contributes at most one assignment per expert) — used for
-        # decode, where per-step dropping would make decode diverge from
-        # prefill.
-        C = T
-    else:
-        C = max(int(math.ceil(T * k / max(E_real, 1) * capacity_factor)), 1)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = idx.reshape(-1)                                  # (T*k,)
+        flat_w = w.reshape(-1)
+        flat_t = jnp.repeat(jnp.arange(T), k)
+        if cfg.moe.dispatch == "cumsum":
+            # Switch-style rank computation: position-in-expert = number
+            # of prior assignments to the same expert, via a cumsum over
+            # the (T·k, E) one-hot — no sort. Same (t, j)-order capacity
+            # semantics as the stable sort, ~10x fewer HLO bytes (see
+            # EXPERIMENTS.md §Perf).
+            ohf = (flat_e[:, None] == jnp.arange(e_pad)[None, :]) \
+                .astype(jnp.float32)                           # (T*k, E)
+            prior = jnp.cumsum(ohf, axis=0) - ohf
+            pos_in_e = jnp.sum(prior * ohf, axis=1).astype(jnp.int32)
+            se, st, sw = flat_e, flat_t, flat_w
+        else:
+            order = jnp.argsort(flat_e, stable=True)
+            se, st, sw = flat_e[order], flat_t[order], flat_w[order]
+            seg_start = jnp.searchsorted(se, jnp.arange(e_pad))
+            pos_in_e = jnp.arange(T * k) - seg_start[se]
+        local_e = se - e_start                                # local expert id
+        in_shard = (local_e >= 0) & (local_e < e_local)
+        keep = (pos_in_e < C) & in_shard
+        # out-of-shard / over-capacity rows scatter out of bounds -> dropped
+        scat_e = jnp.where(keep, local_e, e_local)
+        scat_c = jnp.where(keep, pos_in_e, C)
 
-    flat_e = idx.reshape(-1)                                      # (T*k,)
-    flat_w = w.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    if cfg.moe.dispatch == "cumsum":
-        # Switch-style rank computation: position-in-expert = number of
-        # prior assignments to the same expert, via a cumsum over the
-        # (T·k, E) one-hot — no sort. Same (t, j)-order capacity
-        # semantics as the stable sort, ~10x fewer HLO bytes (see
-        # EXPERIMENTS.md §Perf).
-        ohf = (flat_e[:, None] == jnp.arange(e_pad)[None, :]) \
-            .astype(jnp.float32)                               # (T*k, E)
-        prior = jnp.cumsum(ohf, axis=0) - ohf
-        pos_in_e = jnp.sum(prior * ohf, axis=1).astype(jnp.int32)
-        se, st, sw = flat_e, flat_t, flat_w
-    else:
-        order = jnp.argsort(flat_e, stable=True)
-        se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-        seg_start = jnp.searchsorted(se, jnp.arange(e_pad))
-        pos_in_e = jnp.arange(T * k) - seg_start[se]
-    local_e = se - e_start                                        # local expert id
-    in_shard = (local_e >= 0) & (local_e < e_local)
-    keep = (pos_in_e < C) & in_shard
-    # out-of-shard / over-capacity rows scatter out of bounds -> dropped
-    scat_e = jnp.where(keep, local_e, e_local)
-    scat_c = jnp.where(keep, pos_in_e, C)
+        buf = jnp.zeros((e_local, C, d), x2d.dtype)
+        buf = buf.at[scat_e, scat_c].set(x2d[st], mode="drop")
 
-    buf = jnp.zeros((e_local, C, d), x2d.dtype)
-    buf = buf.at[scat_e, scat_c].set(x2d[st], mode="drop")
-    out_buf = _expert_ffn(cfg, params, buf)                       # (E_l,C,d)
+    with jax.named_scope("moe.experts"):
+        out_buf = _expert_ffn(cfg, params, buf)                   # (E_l,C,d)
 
-    vals = out_buf.at[scat_e, scat_c].get(
-        mode="fill", fill_value=0.0)                              # (T*k,d)
-    vals = vals * jnp.where(keep, sw, 0.0)[:, None].astype(vals.dtype)
-    y = jnp.zeros((T, d), vals.dtype).at[st].add(vals)
+    with jax.named_scope("moe.combine"):
+        vals = out_buf.at[scat_e, scat_c].get(
+            mode="fill", fill_value=0.0)                          # (T*k,d)
+        vals = vals * jnp.where(keep, sw, 0.0)[:, None].astype(vals.dtype)
+        y = jnp.zeros((T, d), vals.dtype).at[st].add(vals)
     return y, (load_sum, prob_sum, z_sum, jnp.float32(T))
 
 

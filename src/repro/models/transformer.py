@@ -164,20 +164,28 @@ def block_apply(cfg: ModelConfig, p: dict, x, kind: str, *,
     if cfg.parallel_block and ("mlp" in p or "moe" in p):
         y, aux = _ffn(cfg, p, h, spmd=spmd, capacity_factor=capacity_factor,
                       impl=impl, dropless=dropless)
-        x = x + mix + y
+        x = x + _residual(cfg, mix) + _residual(cfg, y)
     else:
-        x = x + mix
+        x = x + _residual(cfg, mix)
         if "cross" in p and memory_kv is not None:
             hc = rms_norm(x, p["cross_norm"], cfg.norm_eps)
-            x = x + attn_mod.cross_attn_apply(cfg, p["cross"], hc, memory_kv,
-                                              impl=impl)
+            x = x + _residual(cfg, attn_mod.cross_attn_apply(
+                cfg, p["cross"], hc, memory_kv, impl=impl))
         if "mlp" in p or "moe" in p:
             h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
             y, aux = _ffn(cfg, p, h2, spmd=spmd,
                           capacity_factor=capacity_factor, impl=impl,
                           dropless=dropless)
-            x = x + y
+            x = x + _residual(cfg, y)
     return x, aux, new_cache
+
+
+def _residual(cfg: ModelConfig, branch):
+    """A branch's output as it joins the residual stream: scaled by
+    ``residual_multiplier`` (granite), untouched at 1."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
 def _layer_of(cache, layer):
@@ -503,6 +511,25 @@ class Model:
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         logits = unembed(cfg, params["embed"], h)[:, 0]
         return logits, new_cache
+
+    # ------------------------------------------------------------ MoE work
+    @property
+    def moe_layers(self) -> int:
+        """Layers whose feed-forward half is the mixture of experts."""
+        cfg = self.cfg
+        if not cfg.moe.num_experts or cfg.d_ff <= 0:
+            return 0
+        return cfg.n_layers - self.prefix_count
+
+    def moe_rows(self, tokens: int) -> int:
+        """Expert-FFN rows that a serving call over ``tokens`` tokens
+        computes, summed over the stack: padded experts × the dropless
+        dispatch's capacity × MoE layers."""
+        if not self.moe_layers:
+            return 0
+        C = moe_mod.capacity(self.cfg, tokens, self.cfg.moe.capacity_factor,
+                             dropless=True)
+        return self.e_pad * C * self.moe_layers
 
     # ------------------------------------------------------------ chunked
     @property

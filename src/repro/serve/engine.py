@@ -34,7 +34,8 @@ correctness never depends on the cache (a miss is just a full prefill).
 ``serve.step``), each a profiler annotation on the device trace's clock
 and a pair of ``serve.engine.phase_*`` counters; ``stats()`` carries the
 per-engine totals with the decode steps and tokens and the prefill
-chunks and real tokens they moved.
+chunks and real tokens they moved, and, for a mixture of experts, the
+routed assignments and the expert rows the dispatch computed.
 
 The Mercury serving gateway (services/gateway.py) drives this engine from
 RPC handlers; ``generate()`` is the synchronous convenience wrapper used
@@ -68,6 +69,8 @@ _M_DECODE_STEPS = _metrics.counter("serve.engine.decode_steps")
 _M_DECODE_TOKENS = _metrics.counter("serve.engine.decode_tokens")
 _M_PREFILL_CHUNKS = _metrics.counter("serve.engine.prefill_chunks")
 _M_PREFILL_TOKENS = _metrics.counter("serve.engine.prefill_tokens")
+_M_MOE_ASSIGNMENTS = _metrics.counter("serve.engine.moe_assignments")
+_M_MOE_ROWS = _metrics.counter("serve.engine.moe_rows")
 
 # the phases of step(), each a profiler annotation plus a pair of
 # counters by phase; all nest in serve.step
@@ -201,6 +204,11 @@ class ServeEngine:
         self.decode_tokens = 0        # sampled from decode steps
         self.prefill_chunks = 0
         self.prefill_tokens = 0       # real (unpadded) tokens of chunks
+        # MoE work from shapes alone: routed (token, expert) assignments
+        # of real tokens, and the expert-FFN rows the dispatch computes
+        self.moe_assignments = 0
+        self.moe_rows = 0
+        self._moe_per_token = model.cfg.moe.top_k * model.moe_layers
         self._phase = Phases(_M_PHASE_NS, _M_PHASE_CALLS)
 
         def prefill(p, b):
@@ -317,6 +325,8 @@ class ServeEngine:
                 "decode_tokens": self.decode_tokens,
                 "prefill_chunks": self.prefill_chunks,
                 "prefill_tokens": self.prefill_tokens,
+                "moe_assignments": self.moe_assignments,
+                "moe_rows": self.moe_rows,
                 "phase_ns": dict(self._phase.ns),
                 "phase_calls": dict(self._phase.calls)}
 
@@ -418,12 +428,12 @@ class ServeEngine:
         if req.frontend is not None:
             batch["frontend"] = self._put(req.frontend[None])
         logits, cache1 = self._prefill_jit(self.params, batch)
+        prompt_span = len(req.prompt) + (
+            self.model.cfg.frontend_seq if req.frontend is not None else 0)
+        self._count_moe(prompt_span, prompt_span)
         self.cache = self._scatter_slot(self.cache, cache1, slot)
         with self._phase("serve.sample"):
             tok = self._sample(logits[0], req)
-            prompt_span = len(req.prompt) + (
-                self.model.cfg.frontend_seq
-                if req.frontend is not None else 0)
             self.pos[slot] = prompt_span
             self.last_tok[slot] = tok
             self._emit(req, tok)
@@ -462,6 +472,7 @@ class ServeEngine:
         self.prefill_tokens += real
         _M_PREFILL_CHUNKS.inc()
         _M_PREFILL_TOKENS.inc(real)
+        self._count_moe(real, C)
         st["off"] += C
         if st["off"] < st["n"]:
             return
@@ -544,6 +555,19 @@ class ServeEngine:
         self.decode_tokens += sampled
         _M_DECODE_STEPS.inc()
         _M_DECODE_TOKENS.inc(sampled)
+        self._count_moe(len(active), self.n_slots)
+
+    def _count_moe(self, real: int, tokens: int) -> None:
+        """The MoE work of one call over ``tokens`` tokens, ``real`` of
+        them a request's: their routed assignments, and the expert rows
+        the model's dispatch computes for a call of that size."""
+        if not self._moe_per_token:
+            return
+        n, rows = real * self._moe_per_token, self.model.moe_rows(tokens)
+        self.moe_assignments += n
+        self.moe_rows += rows
+        _M_MOE_ASSIGNMENTS.inc(n)
+        _M_MOE_ROWS.inc(rows)
 
     def fail_all(self, reason: str) -> int:
         """Fail every request the engine holds — decoding, mid-prefill,

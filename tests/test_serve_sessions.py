@@ -250,6 +250,45 @@ def test_work_counters_and_phases(model_and_params):
         st2["prefill_chunks"]
 
 
+@pytest.mark.parametrize("chunk", [8, 0])
+def test_moe_work_counters(chunk):
+    """A MoE engine counts, from shapes alone, the routed assignments of
+    its real tokens (tokens × k × MoE layers) and the expert rows its
+    dropless dispatch computes (E × C × layers, C the tokens of the call:
+    a chunk's size, the slots of a decode step, a whole prompt without
+    chunks), in stats() and in the registry's counters alike."""
+    from repro.telemetry import metrics
+    cfg = configs.reduced("granite-moe-3b-a800m").replace(
+        compute_dtype="float32")
+    m = Model(cfg)
+    params, _ = unzip(m.init(jax.random.PRNGKey(0)))
+    reg = {name: metrics.counter(f"serve.engine.{name}")
+           for name in ("moe_assignments", "moe_rows")}
+    before = {name: c.value for name, c in reg.items()}
+    n_slots = 3
+    eng = make_engine(m, params, n_slots=n_slots, chunk_tokens=chunk)
+    prompts = [np.arange(1, 20), np.arange(2, 7), np.arange(3, 19)]
+    eng.generate(prompts, max_new=4)
+    st = eng.stats()
+    E, k, L = cfg.moe.num_experts, cfg.moe.top_k, cfg.n_layers
+    prompt_tokens = sum(map(len, prompts))
+    assert st["moe_assignments"] == \
+        (prompt_tokens + st["decode_tokens"]) * k * L
+    prefill_rows = st["prefill_chunks"] * chunk if chunk else prompt_tokens
+    assert st["moe_rows"] == \
+        (prefill_rows + st["decode_steps"] * n_slots) * E * L
+    for name, c in reg.items():
+        assert c.value - before[name] == st[name], name
+
+
+def test_dense_engine_counts_no_moe_work(model_and_params):
+    m, params = model_and_params
+    eng = make_engine(m, params, n_slots=2, chunk_tokens=8)
+    eng.generate([np.arange(1, 12)], max_new=3)
+    st = eng.stats()
+    assert st["moe_assignments"] == st["moe_rows"] == 0
+
+
 # -------------------------------------------------------------- donation
 @pytest.fixture
 def donating(monkeypatch):
