@@ -29,13 +29,21 @@ chunk path, at an offset).  Pinned slots are evicted LRU-first whenever
 a fresh request needs a slot or the table exceeds ``session_cap``;
 correctness never depends on the cache (a miss is just a full prefill).
 
+**Sampling on the device**: every token is chosen by one jitted
+sampler (``jit_sample_tokens``) over a call's logits — all the slots
+of a decode step, or the last real row of a prompt's last chunk —
+greedy where a row's temperature is 0, drawn under the engine's
+device-held key elsewhere; the host reads back one small token array
+per call (``token_reads``).
+
 **Phases and work counters**: ``step()`` runs in named phases
 (``PHASES``: admit, slot copy, prefill chunk, decode, sample, all inside
 ``serve.step``), each a profiler annotation on the device trace's clock
 and a pair of ``serve.engine.phase_*`` counters; ``stats()`` carries the
 per-engine totals with the decode steps and tokens and the prefill
-chunks and real tokens they moved, and, for a mixture of experts, the
-routed assignments and the expert rows the dispatch computed.
+chunks and real tokens they moved, the token reads, and, for a mixture
+of experts, the routed assignments and the expert rows the dispatch
+computed.
 
 The Mercury serving gateway (services/gateway.py) drives this engine from
 RPC handlers; ``generate()`` is the synchronous convenience wrapper used
@@ -71,6 +79,7 @@ _M_PREFILL_CHUNKS = _metrics.counter("serve.engine.prefill_chunks")
 _M_PREFILL_TOKENS = _metrics.counter("serve.engine.prefill_tokens")
 _M_MOE_ASSIGNMENTS = _metrics.counter("serve.engine.moe_assignments")
 _M_MOE_ROWS = _metrics.counter("serve.engine.moe_rows")
+_M_TOKEN_READS = _metrics.counter("serve.engine.token_reads")
 
 # the phases of step(), each a profiler annotation plus a pair of
 # counters by phase; all nest in serve.step
@@ -151,6 +160,29 @@ def decode_executable(model: Model, impl: str, *, donate: bool):
     return jax.jit(decode_step, donate_argnums=(1,) if donate else ())
 
 
+def sample_executable():
+    """The engine's sampler, jitted as ``jit_sample_tokens``: one token
+    for each of the rows ``first .. first + len(temperature)`` of
+    ``logits`` (its leading dims flattened into rows), the first argmax
+    where the row's ``temperature`` is 0, else a draw from
+    softmax(logits / T) under a key split inside the call from ``key``.
+    Returns the ``(len(temperature),)`` int32 tokens and the next key."""
+    def sample_tokens(logits, first, temperature, key):
+        logits = jax.lax.dynamic_slice_in_dim(
+            logits.reshape(-1, logits.shape[-1]), first, temperature.shape[0])
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        hot = temperature > 0
+        key, draw_key = jax.random.split(key)
+
+        def draw():
+            t = jnp.where(hot, temperature, 1.0)[:, None]
+            drawn = jax.random.categorical(draw_key, logits / t, axis=-1)
+            return jnp.where(hot, drawn.astype(jnp.int32), greedy)
+        # an all-greedy call draws no noise over the vocabulary
+        return jax.lax.cond(jnp.any(hot), draw, lambda: greedy), key
+    return jax.jit(sample_tokens)
+
+
 class ServeEngine:
     """``device`` pins the engine: parameters, the batched cache, the B=1
     staging cache and every jitted output live on that one device, so
@@ -208,6 +240,7 @@ class ServeEngine:
         # of real tokens, and the expert-FFN rows the dispatch computes
         self.moe_assignments = 0
         self.moe_rows = 0
+        self.token_reads = 0          # device->host copies of tokens
         self._moe_per_token = model.cfg.moe.top_k * model.moe_layers
         self._phase = Phases(_M_PHASE_NS, _M_PHASE_CALLS)
 
@@ -222,6 +255,7 @@ class ServeEngine:
         donate = donates(device)
         self._prefill_jit = jax.jit(prefill)
         self._decode_jit = decode_executable(model, impl, donate=donate)
+        self._sample_jit = sample_executable()
         if self.chunk or self.session_cap:
             self._chunk_jit = jax.jit(prefill_chunk)
             # zeroed B=1 staging cache, shared template for fresh prompts
@@ -327,6 +361,7 @@ class ServeEngine:
                 "prefill_tokens": self.prefill_tokens,
                 "moe_assignments": self.moe_assignments,
                 "moe_rows": self.moe_rows,
+                "token_reads": self.token_reads,
                 "phase_ns": dict(self._phase.ns),
                 "phase_calls": dict(self._phase.calls)}
 
@@ -433,7 +468,7 @@ class ServeEngine:
         self._count_moe(prompt_span, prompt_span)
         self.cache = self._scatter_slot(self.cache, cache1, slot)
         with self._phase("serve.sample"):
-            tok = self._sample(logits[0], req)
+            tok = int(self._sample(logits, [req])[0])
             self.pos[slot] = prompt_span
             self.last_tok[slot] = tok
             self._emit(req, tok)
@@ -482,17 +517,26 @@ class ServeEngine:
         self.cache = self._scatter_slot(self.cache, st["cache1"], slot)
         self.pos[slot] = st["base"] + st["n"]
         with self._phase("serve.sample"):
-            tok = self._sample(logits[0, last], req)
+            tok = int(self._sample((logits, last), [req])[0])
             self.last_tok[slot] = tok
             self._emit(req, tok)
         if req.done_event.is_set():
             self._release_slot(slot)
 
-    def _sample(self, logits, req: Request) -> int:
-        if req.temperature <= 0.0:
-            return int(jnp.argmax(logits))
-        self._rng, k = jax.random.split(self._rng)
-        return int(jax.random.categorical(k, logits / req.temperature))
+    def _sample(self, logits, reqs) -> np.ndarray:
+        """The tokens of one call's ``logits`` (leading dims flattened
+        into rows), one per entry of ``reqs``, which lines up with the
+        rows from 0, or from ``row`` where ``logits`` is a pair
+        ``(logits, row)`` (None: a row nobody reads, sampled greedily):
+        one sampler dispatch, then one device->host copy of the tokens."""
+        logits, first = logits if isinstance(logits, tuple) else (logits, 0)
+        temps = np.asarray([0.0 if r is None else r.temperature
+                            for r in reqs], np.float32)
+        toks, self._rng = self._sample_jit(logits, np.int32(first), temps,
+                                           self._rng)
+        self.token_reads += 1
+        _M_TOKEN_READS.inc()
+        return jax.device_get(toks)
 
     def _emit(self, req: Request, tok: int):
         if not req.out_tokens:
@@ -521,8 +565,8 @@ class ServeEngine:
             return sum(1 for r in self.slot_req if r is not None)
 
     def _decode(self, active: List[int]) -> None:
-        """One decode step for the ``active`` slots, then sample and emit
-        each slot's token."""
+        """One decode step for the ``active`` slots, then one sampler call
+        over every slot's row and an emit of each active slot's token."""
         with self._phase("serve.decode"):
             # copies: the host arrays advance while the step may still
             # be reading its inputs
@@ -530,19 +574,23 @@ class ServeEngine:
             pos = self._put(self.pos.copy())
             logits, self.cache = self._decode_jit(self.params, self.cache,
                                                   toks, pos)
-            # the first sample's int() would block on these logits
+            # the sampler's token read would block on these logits
             # anyway; waiting here keeps the device's decode inside
-            # serve.decode and only the host's per-slot work in
+            # serve.decode and only the sampler and the per-slot emit in
             # serve.sample
             jax.block_until_ready(logits)
         sampled = 0
         with self._phase("serve.sample"):
+            rows: List[Optional[Request]] = [None] * self.n_slots
+            for i in active:
+                rows[i] = self.slot_req[i]
+            tokens = self._sample(logits, rows).tolist()
             for i in active:
                 req = self.slot_req[i]
                 if req.done_event.is_set():
                     self._release_slot(i)
                     continue
-                tok = self._sample(logits[i], req)
+                tok = tokens[i]
                 sampled += 1
                 self.pos[i] += 1
                 self.last_tok[i] = tok

@@ -289,6 +289,154 @@ def test_dense_engine_counts_no_moe_work(model_and_params):
     assert st["moe_assignments"] == st["moe_rows"] == 0
 
 
+# -------------------------------------------------------------- sampling
+def _per_row_argmax(logits, reqs):
+    """Sampling as a per-slot loop did it: the first argmax of each
+    row, one row at a time (greedy requests only)."""
+    logits, first = logits if isinstance(logits, tuple) else (logits, 0)
+    rows = logits.reshape(-1, logits.shape[-1])
+    return np.asarray([int(jnp.argmax(rows[first + i]))
+                       for i in range(len(reqs))], np.int32)
+
+
+def _turns(eng):
+    """Two turns over three slots, chunked: three prompts (one of them a
+    session), then a follow-up that resumes the session beside a fresh
+    prompt."""
+    prompts = [np.arange(1, 20), np.arange(2, 7), np.arange(3, 19)]
+    outs = eng.generate(prompts, max_new=6, session_ids=["a", None, None])
+    follow = np.concatenate([prompts[0], np.asarray(outs[0], np.int32),
+                             np.asarray([7, 9], np.int32)])
+    outs += eng.generate([follow, np.arange(5, 30)], max_new=5,
+                         session_ids=["a", None])
+    return outs
+
+
+def test_greedy_tokens_are_each_rows_argmax(model_and_params):
+    """Every token a decode step emits is the first argmax of its own
+    slot's row of that step's logits, over a multi-slot, multi-step,
+    chunked run with a session resume; and every token, first tokens
+    too, is what per-slot argmax sampling gives."""
+    m, params = model_and_params
+    eng = make_engine(m, params, n_slots=3, chunk_tokens=8, session_cap=4)
+    decode = eng._decode_jit
+    steps = []
+
+    def step(p, c, t, pos):
+        logits, nc = decode(p, c, t, pos)
+        live = [(i, r, len(r.out_tokens))
+                for i, r in enumerate(eng.slot_req)
+                if r is not None and i not in eng._prefill
+                and not r.done_event.is_set()]
+        steps.append((np.asarray(logits), live))
+        return logits, nc
+    eng._decode_jit = step
+    outs = _turns(eng)
+    assert eng.stats()["prefix_hits"] == 1
+    checked = 0
+    for logits, live in steps:
+        for i, req, n in live:
+            assert req.out_tokens[n] == int(np.argmax(logits[i]))
+            checked += 1
+    assert checked == eng.stats()["decode_tokens"] > 10
+
+    per_row = make_engine(m, params, n_slots=3, chunk_tokens=8,
+                          session_cap=4)
+    per_row._sample = _per_row_argmax
+    assert _turns(per_row) == outs
+
+
+def test_one_token_read_per_decode_step_and_prompt(model_and_params):
+    """One device->host copy of tokens per sampler call: per decode step,
+    and per completed prompt (chunked or monolithic); ``_sample`` is the
+    one method every emitted token passes through."""
+    from repro.telemetry import metrics
+    m, params = model_and_params
+    reg = metrics.counter("serve.engine.token_reads")
+    for chunk in (8, 0):
+        before = reg.value
+        eng = make_engine(m, params, n_slots=3, chunk_tokens=chunk)
+        sample, calls = eng._sample, []
+
+        def counted(logits, reqs):
+            toks = sample(logits, reqs)
+            calls.append(len(reqs))
+            return toks
+        eng._sample = counted
+        prompts = [np.arange(1, 20), np.arange(2, 7), np.arange(3, 19),
+                   np.arange(4, 9)]
+        outs = eng.generate(prompts, max_new=5)
+        st = eng.stats()
+        assert st["token_reads"] == st["decode_steps"] + len(prompts)
+        assert st["token_reads"] == len(calls) == reg.value - before
+        assert calls.count(3) == st["decode_steps"]
+        assert st["decode_tokens"] + len(prompts) == sum(map(len, outs))
+
+
+def test_sampler_rows_and_temperatures():
+    """The sampler alone: a greedy row takes the first of tied maxima,
+    the key advances on every call, and a row at temperature T draws
+    from softmax(logits / T)."""
+    sample = engine_mod.sample_executable()
+    key = jax.random.PRNGKey(3)
+    n = 4000
+    logits = np.zeros((n, 4), np.float32)
+    logits[:, 1] = logits[:, 3] = np.log(3.0)       # a tie at 1 and 3
+    logits[:, 0] = np.log(1.0)
+    logits[:, 2] = -np.inf
+    temps = np.zeros((n,), np.float32)
+    temps[n // 2:] = 2.0
+    toks, key2 = sample(logits, 0, temps, key)
+    toks = np.asarray(toks)
+    assert toks.dtype == np.int32 and toks.shape == (n,)
+    assert (toks[:n // 2] == 1).all()
+    hot = toks[n // 2:]
+    assert not (hot == 2).any()
+    # softmax(logits / 2) = (1, √3, 0, √3) / (1 + 2√3)
+    want = 1 / (1 + 2 * np.sqrt(3.0))
+    assert abs(np.mean(hot == 0) - want) < 0.04
+    assert not np.array_equal(np.asarray(key2), np.asarray(key))
+    again, _ = sample(logits, 0, temps, key)
+    assert np.array_equal(np.asarray(again), toks)
+    # leading dims flatten into rows, and only the rows from ``first``
+    # are sampled: one row of a chunk's (1, C, V) logits
+    chunk = np.zeros((1, 8, 4), np.float32)
+    chunk[0, np.arange(8), np.arange(8) % 4] = 1.0
+    one, _ = sample(chunk, 5, np.zeros((1,), np.float32), key)
+    assert np.asarray(one).tolist() == [1]
+    two, _ = sample(chunk, 2, np.zeros((2,), np.float32), key)
+    assert np.asarray(two).tolist() == [2, 3]
+
+
+def test_temperature_slots_beside_greedy_ones(model_and_params):
+    """Greedy slots serve the same tokens whatever their neighbours'
+    temperatures; sampled tokens are in the vocabulary, the same for the
+    same engine seed and different for another; a tiny temperature
+    serves the greedy tokens."""
+    m, params = model_and_params
+    prompts = [np.arange(1, 20), np.arange(2, 7), np.arange(3, 19),
+               np.arange(4, 9)]
+
+    def serve(temps, seed=0):
+        eng = make_engine(m, params, n_slots=4, chunk_tokens=8, seed=seed)
+        reqs = [eng.submit(p, max_new=8, temperature=t)
+                for p, t in zip(prompts, temps)]
+        eng.drain()
+        return [r.out_tokens for r in reqs]
+
+    greedy = serve([0.0] * 4)
+    mixed = serve([0.0, 2.0, 0.0, 2.0])
+    assert mixed[0] == greedy[0] and mixed[2] == greedy[2]
+    drawn = mixed[1] + mixed[3]
+    assert all(0 <= t < CFG.vocab for t in drawn)
+    assert mixed != greedy
+    assert serve([0.0, 2.0, 0.0, 2.0]) == mixed
+    other = serve([0.0, 2.0, 0.0, 2.0], seed=1)
+    assert other[1] + other[3] != drawn
+    assert other[0] == greedy[0] and other[2] == greedy[2]
+    assert serve([1e-6] * 4, seed=5) == greedy
+
+
 # -------------------------------------------------------------- donation
 @pytest.fixture
 def donating(monkeypatch):

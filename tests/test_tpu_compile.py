@@ -23,7 +23,7 @@ from repro.kernels.moe_router import router_topk_pallas
 from repro.kernels.rglru_scan import rglru_pallas
 from repro.kernels.ssd import ssd_pallas
 from repro.models import Model, unzip
-from repro.serve.engine import decode_executable
+from repro.serve.engine import decode_executable, sample_executable
 
 HBM_BYTES = 16 * 2 ** 30        # one v5e chip
 
@@ -169,6 +169,27 @@ def test_granite_decode_step_fits_one_chip(one_chip):
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert held <= 10.5e9, held
+
+
+@pytest.mark.parametrize("name,shape,rows", [
+    ("qwen1.5-0.5b", (24, None), 24),      # a decode step's 24 slots
+    ("granite-moe-3b-a800m", (24, None), 24),
+    ("qwen1.5-0.5b", (1, 256, None), 1),   # a prompt's last real row
+])
+def test_sampler_compiles_at_vocab_width(one_chip, name, shape, rows):
+    """The engine's sampler over a call's float32 logits at the model's
+    vocabulary: greedy and temperature rows in one program, the draw
+    behind a branch that an all-greedy call does not take."""
+    vocab = configs.get(name).vocab
+    shape = tuple(vocab if d is None else d for d in shape)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    c = sample_executable().lower(
+        _sds(one_chip, shape, jnp.float32),
+        _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (rows,), jnp.float32),
+        _sds(one_chip, key.shape, key.dtype)).compile()
+    assert " conditional(" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < HBM_BYTES
 
 
 def test_router_topk_granite_width(one_chip):
