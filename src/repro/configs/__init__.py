@@ -1,9 +1,8 @@
 from .base import (ModelConfig, MoEConfig, ParallelConfig, RGLRUConfig,
-                   SHAPES, SSMConfig, ShapeSpec)
-from .registry import all_cells, get, names, reduced, shapes_for
+                   SSMConfig)
+from .registry import get, names, reduced
 
 __all__ = [
     "ModelConfig", "MoEConfig", "SSMConfig", "RGLRUConfig", "ParallelConfig",
-    "ShapeSpec", "SHAPES", "get", "reduced", "names", "shapes_for",
-    "all_cells",
+    "get", "reduced", "names",
 ]

@@ -1,4 +1,4 @@
-"""Model / parallelism / shape configuration.
+"""Model and parallelism configuration.
 
 One :class:`ModelConfig` covers every assigned architecture family
 (dense, MoE, SSM, hybrid, enc-dec, VLM/audio-stub).  The per-layer block
@@ -124,9 +124,8 @@ class ModelConfig:
 
     @property
     def supports_long_context(self) -> bool:
-        """long_500k applies unless the arch is *pure* full attention.
+        """False only for a *pure* full-attention arch.
 
-        Skip rule (assignment): pure full-attention archs skip long_500k.
         A uniform ``attn`` stack is pure; SSM/hybrid and mixes dominated by
         bounded-window blocks (gemma3's 5:1 local:global, recurrentgemma's
         rglru+local) qualify — their decode state is O(window)/O(1) on all
@@ -201,28 +200,6 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
-class ShapeSpec:
-    """One assigned input-shape cell."""
-
-    name: str            # train_4k | prefill_32k | decode_32k | long_500k
-    kind: str            # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-
-@dataclass(frozen=True)
 class ParallelConfig:
     """How a step is laid out on the mesh (see distrib/sharding.py)."""
 
@@ -233,12 +210,7 @@ class ParallelConfig:
     pipeline_stages: int = 1        # >1 enables the PP stage runner
     microbatches: int = 1           # grad-accumulation steps
     remat: str = "block"            # none | block | full
-    seq_shard_decode: bool = True   # shard KV cache sequence over `model`
     compress_grads: bool = False    # int8 all-reduce w/ error feedback
-    decode_twopass: bool = True     # shard_map 2-pass decode softmax
-    param_gather_dtype: str = ""    # "bfloat16": cast params before use so
-                                    # FSDP all-gathers / grad reduces travel
-                                    # in 16-bit (mixed-precision ZeRO-3)
 
     def replace(self, **kw) -> "ParallelConfig":
         return dataclasses.replace(self, **kw)

@@ -1,7 +1,7 @@
 """nemotron-4-340b  [dense]
 96L d_model=18432 96H (GQA kv=8) d_ff=73728 vocab=256000 — squared-ReLU
 MLP, GQA. The largest assigned arch: fitting 16 GB/chip requires full
-ZeRO-3 + TP sharding (see EXPERIMENTS.md dry-run memory analysis).
+ZeRO-3 + TP sharding.
 [arXiv:2402.16819; unverified]
 """
 from .base import ModelConfig

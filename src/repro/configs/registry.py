@@ -7,9 +7,9 @@ same-family variant for CPU smoke tests).
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List
+from typing import List
 
-from .base import ModelConfig, ShapeSpec, SHAPES
+from .base import ModelConfig
 
 _ARCHS = [
     "granite_moe_3b_a800m",
@@ -55,18 +55,3 @@ def get(arch: str) -> ModelConfig:
 
 def reduced(arch: str) -> ModelConfig:
     return _module(arch).reduced()
-
-
-def shapes_for(arch: str) -> Dict[str, ShapeSpec]:
-    """Applicable shape cells for an arch (per assignment rules):
-    ``long_500k`` only for sub-quadratic archs; all archs have decode
-    (seamless decodes with its enc-dec decoder)."""
-    cfg = get(arch)
-    out = dict(SHAPES)
-    if not cfg.supports_long_context:
-        out.pop("long_500k")
-    return out
-
-
-def all_cells() -> List[tuple]:
-    return [(a, s) for a in names() for s in shapes_for(a)]

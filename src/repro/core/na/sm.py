@@ -311,7 +311,7 @@ class SMPlugin(NAPlugin):
         self._tx_lock = threading.Lock()
         self._conns: Dict[str, _SMConn] = {}  #: guarded-by _tx_lock
         # doorbell-coalescing counters (under _tx_lock on the send path):
-        # bells/frames ≪ 1 under burst is the win bench_core asserts
+        # bells/frames ≪ 1 under burst (test_sm_burst_coalesces_doorbells)
         self.stat_frames = 0  #: guarded-by _tx_lock
         self.stat_bells = 0  #: guarded-by _tx_lock
         self._recv_unexpected: Deque[Tuple[NAOp, NACallback]] = deque()

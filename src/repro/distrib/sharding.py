@@ -29,8 +29,8 @@ Rules = Dict[str, Tuple[str, ...]]
 
 
 def abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-less mesh for spec resolution (Auto axes, like the
-    production meshes of launch/mesh.py)."""
+    """Device-less mesh for spec resolution (Auto axes, like
+    launch/mesh.py's)."""
     return AbstractMesh(tuple(shape), tuple(axes),
                         axis_types=(AxisType.Auto,) * len(axes))
 

@@ -92,7 +92,6 @@ class RegistryService:
                  peers: Optional[Sequence[str]] = None,
                  self_uri: Optional[str] = None,
                  lease_ttl: float = 1.0, gossip_interval: float = 0.25,
-                 delta_gossip: bool = True,
                  serve_membership: bool = False,
                  heartbeat_timeout: float = 2.0):
         self.engine = engine
@@ -103,7 +102,7 @@ class RegistryService:
         self.core = ReplicationCore(
             engine, peers=peers, self_uri=self_uri, lease_ttl=lease_ttl,
             gossip_interval=gossip_interval, sweep_interval=sweep_interval,
-            delta_gossip=delta_gossip, autostart=False)
+            autostart=False)
         self.table = self.core.table("instances", ttl=instance_ttl)
         # member ids whose expiry still awaits reaping (follower-hosted
         # MembershipServer; see _members_expired) -> forget-after stamp

@@ -449,7 +449,7 @@ class ReplicationCore:
     sweeper.  With ``peers=None`` the core is a single-node control
     plane: always leading, no gossip, same API.
 
-    **Delta gossip** (default): the leader tracks, per peer, the last
+    **Delta gossip**: the leader tracks, per peer, the last
     acknowledged ``(nonce, per-table epoch)`` — acks arrive both as
     responses to its pushes and as the followers' own heartbeats — and
     pushes only entries versioned past the ack, plus coalesced soft
@@ -457,10 +457,7 @@ class ReplicationCore:
     missing, carries a different nonce, or falls behind a table's
     tombstone horizon is resynced with a **full snapshot** instead
     (rate-limited per peer so a dead peer does not cost a snapshot
-    encode per tick).  ``delta_gossip=False`` restores the PR-4
-    full-state protocol (snapshot on membership change + periodic
-    cadence) — kept as the comparison baseline for the
-    ``gossip_churn`` benchmark and as an operational escape hatch.
+    encode per tick).
     """
 
     def __init__(self, engine, peers: Optional[Sequence[str]] = None,
@@ -468,12 +465,10 @@ class ReplicationCore:
                  gossip_interval: float = 0.25,
                  sweep_interval: float = 0.5,
                  rpc_name: str = "fab.gossip",
-                 delta_gossip: bool = True,
                  tombstone_ttl: Optional[float] = None,
                  autostart: bool = True):
         self.engine = engine
         self.rpc_name = rpc_name
-        self.delta_gossip = delta_gossip
         self.gossip_interval = gossip_interval
         self._lock = threading.RLock()
         self.tables: Dict[str, ReplicatedTable] = {}  #: guarded-by _lock
@@ -520,11 +515,8 @@ class ReplicationCore:
         # with live peers and flap leadership
         self._gossip_timeout = max(0.2, min(self._proxy_timeout,
                                             lease_ttl / 2))
-        # snapshot cadence: the full-state mode's periodic push, and the
-        # delta mode's per-peer rate limit for unacked (dead or cold)
-        # peers
-        self._full_push_every = max(1.0, gossip_interval)
-        self._next_full_push = 0.0  #: guarded-by _lock
+        # per-peer snapshot rate limit for unacked (dead or cold) peers
+        self._snap_push_every = max(1.0, gossip_interval)
         self._sweep_interval = sweep_interval
         self._sweeper = threading.Thread(
             target=self._sweep_loop, args=(sweep_interval,), daemon=True,
@@ -659,14 +651,13 @@ class ReplicationCore:
             return
         now = time.monotonic()
         with self._lock:
-            if nonce == self.nonce and any(
-                    int(s["epoch"]) < self.tables[n].epoch
-                    for n, s in snaps.items() if n in self.tables):
-                return                    # stale push of our own stream
-            # equal-epoch snapshots of our own stream ARE adopted: in
-            # full-gossip mode the leader's periodic snapshot is how
-            # mirrored soft state (loads, liveness ages) stays fresh
-            # between membership changes
+            if nonce == self.nonce:
+                # our own stream: adopt only a push that is ahead of us
+                ahead = [int(s["epoch"]) - self.tables[n].epoch
+                         if n in self.tables else 1
+                         for n, s in snaps.items()]
+                if min(ahead, default=0) < 0 or max(ahead, default=0) == 0:
+                    return
             self._leading = False
             self.nonce = nonce
             for name, snap in snaps.items():
@@ -702,7 +693,7 @@ class ReplicationCore:
         """Build what a behind peer needs: ``("delta", {...})`` when its
         acked epochs connect to our tombstone history, else
         ``("snapshot", {...})``."""
-        if self.delta_gossip and peer_nonce == self.nonce:
+        if peer_nonce == self.nonce:
             deltas = {}
             for name, t in self.tables.items():
                 base = int((peer_epochs or {}).get(name, 0))
@@ -751,17 +742,16 @@ class ReplicationCore:
 
     def _gossip_loop(self) -> None:
         while not self._stop.is_set():
-            dirty = self._dirty.wait(self.gossip_interval)
+            self._dirty.wait(self.gossip_interval)
             self._dirty.clear()
             if self._stop.is_set():
                 return
             try:
-                self._gossip_tick(dirty)
+                self._gossip_tick()
             except Exception:
                 pass                      # gossip must never die
 
-    def _build_pushes_locked(self, dirty: bool, now: float
-                             ) -> List[Tuple[str, dict]]:
+    def _build_pushes_locked(self, now: float) -> List[Tuple[str, dict]]:
         """One payload per peer.  Followers always send the bare
         heartbeat; the leader attaches per-peer deltas / rate-limited
         snapshots as each peer's ack requires."""
@@ -770,15 +760,7 @@ class ReplicationCore:
         peers = self.tracker.others()
         if not self._leading:
             return [(p, base) for p in peers]
-        if not self.delta_gossip:
-            # PR-4 full-state protocol: snapshot rides membership
-            # changes immediately and a slow periodic cadence otherwise
-            payload = dict(base)
-            if dirty or now >= self._next_full_push:
-                payload["snapshot"] = self._snapshots_locked(now)
-                self._next_full_push = now + self._full_push_every
-            return [(p, payload) for p in peers]
-        # delta mode: coalesced soft records (shared across peers) +
+        # coalesced soft records (shared across peers) +
         # per-peer versioned deltas from each acked epoch
         soft = {n: t.take_soft(now) for n, t in self.tables.items()}
         soft = {n: s for n, s in soft.items() if s}
@@ -800,7 +782,7 @@ class ReplicationCore:
                         snaps = self._snapshots_locked(now)
                     out.append((peer, dict(base, snapshot=snaps)))
                     self._next_snap_push[peer] = (now
-                                                  + self._full_push_every)
+                                                  + self._snap_push_every)
                 else:
                     out.append((peer, base))
                 continue
@@ -822,7 +804,7 @@ class ReplicationCore:
                 out.append((peer, base))
         return out
 
-    def _gossip_tick(self, dirty: bool = False) -> None:
+    def _gossip_tick(self) -> None:
         # Leadership changes hands in exactly two places: here (the
         # lease says every higher-priority peer is dead, or — after boot
         # grace — that we are the highest-priority survivor), and in
@@ -835,7 +817,6 @@ class ReplicationCore:
         if (self.tracker.leader_uri() == self.self_uri
                 and not self.is_leader):
             self._take_over()
-            dirty = True
         for hook in self._tick_hooks:
             try:
                 hook()
@@ -843,7 +824,7 @@ class ReplicationCore:
                 pass
         now = time.monotonic()
         with self._lock:
-            pushes = self._build_pushes_locked(dirty, now)
+            pushes = self._build_pushes_locked(now)
         # size/classify the payloads OUTSIDE the lock: the stats encode
         # of a large snapshot would otherwise stall every inline read
         # handler (fab.resolve/fab.epoch/mem.view) contending on it

@@ -5,9 +5,8 @@ Every op has up to four implementations, selected with ``impl=``:
   * ``"ref"``       — the naive oracle in :mod:`repro.kernels.ref`;
   * ``"xla"``       — a memory-efficient pure-jnp implementation (chunked
                       flash attention, blocked local attention, chunked
-                      SSD, associative-scan RG-LRU).  This is the path the
-                      dry-run compiles: its FLOP/byte structure is what the
-                      roofline measures, and on CPU it is the fastest;
+                      SSD, associative-scan RG-LRU); on CPU it is the
+                      fastest;
   * ``"pallas"``    — the Pallas TPU kernel (``pl.pallas_call``), compiled
                       for the MXU/VMEM (TARGET hardware);
   * ``"interpret"`` — the same Pallas kernel in interpret mode (CPU
@@ -30,9 +29,6 @@ from .ref import NEG_INF, RGLRU_C, FLETCHER_MOD
 
 
 def resolve_impl(impl: str) -> str:
-    """"cost" = scan-free variants with identical FLOP structure, used by
-    the dry-run cost compiles (XLA's cost_analysis counts a while-loop
-    body once, so multi-trip scans would undercount)."""
     if impl != "auto":
         return impl
     return "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -65,23 +61,13 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
                                softcap=softcap, q_offset=q_offset,
                                prefix_len=prefix_len, scale=scale,
                                interpret=(impl == "interpret"))
-    # ---- xla / cost path ----
+    # ---- xla path ----
     B, S, Hq, D = q.shape
     T = k.shape[1]
     if S <= 16 and T > 64:
         return _attention_decode(q, k, v, causal=causal, window=window,
                                  softcap=softcap, q_offset=q_offset,
                                  prefix_len=prefix_len, scale=scale)
-    if impl == "cost":
-        # scan-free: naive einsum attention has the same matmul FLOPs as
-        # the chunked/flash path (masking does not reduce einsum FLOPs)
-        if causal and window > 0 and S == T and prefix_len is None \
-                and S >= 2 * window and S % window == 0:
-            return _attention_local_blocked(q, k, v, window=window,
-                                            softcap=softcap, scale=scale)
-        return ref_mod.attention_ref(q, k, v, causal=causal, window=window,
-                                     softcap=softcap, q_offset=q_offset,
-                                     prefix_len=prefix_len, scale=scale)
     if (causal and window > 0 and S == T and prefix_len is None
             and S >= 2 * window and S % window == 0):
         return _attention_local_blocked(q, k, v, window=window,
@@ -256,8 +242,6 @@ def ssd(x, dt, A, B, C, D=None, h0=None, *, chunk: int = 256,
         impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD. Shapes as in :func:`repro.kernels.ref.ssd_ref`."""
     impl = resolve_impl(impl)
-    if impl == "cost":
-        impl = "xla"   # _ssd_chunked is already scan-free in its hot path
     if impl == "ref":
         return ref_mod.ssd_ref(x, dt, A, B, C, D, h0)
     if impl in ("pallas", "interpret"):
@@ -357,8 +341,6 @@ def ssd_decode_step(h, x_t, dt_t, A, B_t, C_t, D=None):
 # ===========================================================================
 def rglru(x, r_gate, i_gate, log_lambda, h0=None, *, impl: str = "auto"):
     impl = resolve_impl(impl)
-    if impl == "cost":
-        impl = "xla"   # associative_scan is an unrolled log-depth network
     if impl == "ref":
         return ref_mod.rglru_ref(x, r_gate, i_gate, log_lambda, h0)
     if impl in ("pallas", "interpret"):
@@ -407,8 +389,6 @@ def rglru_decode_step(h, x_t, r_gate_t, i_gate_t, log_lambda):
 # ===========================================================================
 def router_topk(logits, k: int, *, impl: str = "auto"):
     impl = resolve_impl(impl)
-    if impl == "cost":
-        impl = "xla"
     if impl in ("pallas", "interpret"):
         from .moe_router import router_topk_pallas
         return router_topk_pallas(logits, k, interpret=(impl == "interpret"))

@@ -11,8 +11,7 @@ table and the membership service's member table ride the same leader
 lease and delta-gossip stream (``mem.*`` is served by every node —
 follower reads, writes proxied to the leaseholder), so member liveness
 and expiry reaps survive leaseholder death.  ``--no-membership`` turns
-the membership service off; ``--full-gossip`` falls back to full-state
-snapshot gossip (the delta protocol is the default).
+the membership service off.
 
 **Sharding** (DESIGN.md §12): ``--shards M`` splits the name space
 across M independent quorums by rendezvous hash.  Shard ``k`` listens
@@ -112,9 +111,6 @@ def main(argv=None):
     ap.add_argument("--heartbeat-timeout", type=float, default=2.0,
                     help="seconds without a mem.heartbeat before a "
                          "member is expired")
-    ap.add_argument("--full-gossip", action="store_true",
-                    help="replicate with full-state snapshot gossip "
-                         "instead of per-entry deltas (debug/fallback)")
     ap.add_argument("--trace-sample", type=float, default=None,
                     metavar="P",
                     help="head-sampling probability for distributed "
@@ -145,7 +141,6 @@ def main(argv=None):
                       if args.self_uri else None),
             lease_ttl=args.lease_ttl,
             gossip_interval=args.gossip_interval,
-            delta_gossip=not args.full_gossip,
             serve_membership=args.membership and k == 0,
             heartbeat_timeout=args.heartbeat_timeout)
         engines.append(engine)

@@ -3,8 +3,8 @@ processes fetch batches over RPC.
 
 Small batches ride inline in the RPC response (eager); large ones go
 through a bulk descriptor the trainer pulls one-sidedly — the
-eager/rendezvous crossover is a constructor knob and is *benchmarked* in
-``benchmarks/bench_bulk.py`` (the paper's bulk-vs-eager trade-off).
+eager/rendezvous crossover is a constructor knob (the paper's
+bulk-vs-eager trade-off).
 
 The client keeps ``depth`` requests outstanding (async prefetch), so one
 slow feeder response never stalls the training step; combined with

@@ -7,14 +7,13 @@ State layout (plain value pytree, shardable with distrib.tree_shardings):
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ParallelConfig
 from ..models import Model, unzip
-from ..models.common import P, is_p
 from ..models.moe import MoESpmd
 from . import optim
 
@@ -41,29 +40,6 @@ def init_state(model: Model, opt_cfg: optim.OptConfig, rng):
             opt_p = optim.cast_state(opt_p, opt_cfg.state_dtype)
     state_p = {"params": params_p, "opt": opt_p}
     values, axes = unzip(state_p)
-    return values, axes
-
-
-def state_specs(model: Model, opt_cfg: optim.OptConfig):
-    """Abstract state (ShapeDtypeStructs) + axes via eval_shape — no
-    allocation; used by the dry-run."""
-    def build(rng):
-        v, _ = init_state(model, opt_cfg, rng)
-        return v
-    shapes = jax.eval_shape(build, jax.random.PRNGKey(0))
-    _, axes = init_state_axes(model, opt_cfg)
-    return shapes, axes
-
-
-def init_state_axes(model: Model, opt_cfg: optim.OptConfig):
-    """Axes tree only (cheap: init under eval_shape)."""
-    def build(rng):
-        params_p = model.init(rng)
-        opt_p = optim.adafactor_init(params_p) \
-            if opt_cfg.name == "adafactor" else optim.adamw_init(params_p)
-        return {"params": params_p, "opt": opt_p}
-    tree_p = jax.eval_shape(build, jax.random.PRNGKey(0))
-    values, axes = unzip(tree_p)
     return values, axes
 
 

@@ -5,8 +5,8 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-# IMPORTANT: the dry-run's 512-device override must never leak into tests;
-# smoke tests and benches see the host's real (1-device) platform.
+# IMPORTANT: a host-device-count override in the caller's XLA_FLAGS must
+# never leak into tests; they see the host's real (1-device) platform.
 os.environ.pop("XLA_FLAGS", None)
 
 # Opt-in lock-order sanitizer (DESIGN.md §11): REPRO_LOCKDEP=1 patches the

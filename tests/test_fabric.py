@@ -18,7 +18,7 @@ from repro.core.executor import Engine, RemoteError
 from repro.core.types import Ret
 from repro.fabric import (BudgetExhausted, CreditGate, EwmaWeighted,
                           RegistryClient, RegistryService, RetryPolicy,
-                          ServiceInstance, ServicePool,
+                          ServiceInstance, ServicePool, SessionAffinity,
                           resolve_service_uris)
 from repro.fabric.pool import Replica
 from repro.serve.engine import Request
@@ -836,6 +836,51 @@ def test_gateway_self_registers_and_routes_through_pool(reg):
         assert all(pool.call("gen.generate",
                              {"tokens": [3], "max_new": 2},
                              timeout=15.0)["done"] for _ in range(4))
+    gws[1].close()
+    engines[1].shutdown()
+
+
+def test_session_affinity_rehomes_across_replica_kill(reg):
+    """Multi-turn sessions over a routed pool: follow-ups return to
+    their home gateway; once that replica is killed, every turn still
+    completes and its sessions move to the survivor (a fresh-prefill
+    route, never a lost request)."""
+    reg_e, _ = reg
+    serves = [FakeServe(), FakeServe()]
+    engines = [Engine("tcp://127.0.0.1:0") for _ in serves]
+    gws = [ServingGateway(e, s, registry=reg_e.uri, service="gen-sess",
+                          report_interval=0.1)
+           for e, s in zip(engines, serves)]
+    sessions = [f"conv{i}" for i in range(4)]
+    with Engine("tcp://127.0.0.1:0") as cli:
+        pool = ServicePool(cli, reg_e.uri, "gen-sess", balancer="rr",
+                           refresh_interval=0.1,
+                           policy=RetryPolicy(attempts=4, rpc_timeout=5.0,
+                                              backoff_base=0.01))
+        assert len(pool.replicas()) == 2
+        aff = SessionAffinity(pool)
+
+        def turn(sid):
+            res, iid = aff.call_routed(
+                sid, "gen.generate",
+                {"tokens": [1, 2], "max_new": 2, "session_id": sid},
+                timeout=15.0)
+            assert res["done"] and res["tokens"], (sid, res)
+            return iid
+
+        homes = {sid: turn(sid) for sid in sessions}
+        assert all(turn(sid) == homes[sid] for sid in sessions)
+        assert aff.hits == len(sessions)          # follow-ups went home
+        dead = gws[0].instance.iid
+        live = gws[1].instance.iid
+        orphaned = [sid for sid in sessions if homes[sid] == dead]
+        assert orphaned, homes                    # rr spread the first turn
+        gws[0].instance.close(deregister=False)
+        gws[0].stop()
+        engines[0].shutdown()
+        assert all(turn(sid) == live for sid in sessions)
+        assert aff.moves == len(orphaned)
+        assert all(aff.lookup(sid) == live for sid in sessions)
     gws[1].close()
     engines[1].shutdown()
 

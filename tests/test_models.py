@@ -112,7 +112,7 @@ def test_vector_pos_decode_matches_scalar(arch):
 
 
 def test_long_context_flags():
-    longs = [a for a in ARCHS if "long_500k" in configs.shapes_for(a)]
+    longs = [a for a in ARCHS if configs.get(a).supports_long_context]
     assert sorted(longs) == ["gemma3-12b", "mamba2-1.3b",
                              "recurrentgemma-9b"]
 

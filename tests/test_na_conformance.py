@@ -354,3 +354,31 @@ def test_sm_cross_process_messaging_and_rma():
         assert "CHILD_OK SUCCESS" in out, out + err
     finally:
         parent.finalize()
+
+
+@pytest.mark.parametrize("n_frames", [20, 200])
+def test_sm_burst_coalesces_doorbells(n_frames):
+    """A burst queued while the consumer does not progress rings the
+    peer's doorbell O(1) times, not once per frame (the coalesced send
+    path rings only on the ring's idle→busy transition and on ring-full
+    liveness probes), and every frame still arrives once it drains."""
+    tag = uuid.uuid4().hex[:8]
+    a = SMPlugin(f"sm://burst-a-{tag}")
+    b = SMPlugin(f"sm://burst-b-{tag}")
+    try:
+        got = []
+        for _ in range(n_frames):
+            b.msg_recv_unexpected(
+                lambda ret, src, t, data: got.append(bytes(data)))
+        dst = a.addr_lookup(b.addr_self().uri)
+        for i in range(n_frames):
+            a.msg_send_unexpected(dst, b"y" * 64, i, lambda ret: None)
+        frames, bells = a.stat_frames, a.stat_bells
+        spin([b, a], lambda: len(got) == n_frames, timeout=10.0)
+        assert frames == n_frames
+        assert bells <= max(4, n_frames // 10), \
+            f"{bells} doorbell writes for {n_frames} queued frames"
+        assert got == [b"y" * 64] * n_frames
+    finally:
+        a.finalize()
+        b.finalize()

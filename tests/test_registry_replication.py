@@ -725,36 +725,3 @@ def test_register_member_rebind_is_versioned():
         assert cli.epoch() == e1 + 1, "member rebind must be versioned"
         assert svc.table.get(f"svc\x1f{iid}")["member_id"] == "b"
         svc.close()
-
-
-@pytest.mark.slow
-def test_full_gossip_refreshes_mirrored_soft_state():
-    """--full-gossip compatibility: converged followers must keep
-    adopting the leader's equal-epoch periodic snapshots — that is how
-    mirrored loads stay fresh between membership changes."""
-    engines = [Engine("tcp://127.0.0.1:0") for _ in range(2)]
-    peers = [e.uri for e in engines]
-    regs = [RegistryService(e, peers=peers, lease_ttl=LEASE,
-                            gossip_interval=GOSSIP, sweep_interval=0.1,
-                            instance_ttl=3600.0, delta_gossip=False)
-            for e in engines]
-    try:
-        _wait(lambda: regs[0].is_leader, msg="leadership")
-        with Engine("tcp://127.0.0.1:0") as cli:
-            lead = RegistryClient(cli, peers[0])
-            iid = lead.register("svc", "tcp://127.0.0.1:9700")
-            _wait(lambda: regs[1].epoch == regs[0].epoch,
-                  msg="convergence")
-            lead.report("svc", iid, load=7.5)     # soft: no epoch bump
-            fol = RegistryClient(cli, peers[1])
-            _wait(lambda: [i["load"] for i in
-                           fol.resolve("svc")["instances"]] == [7.5],
-                  msg="mirrored load refresh under full-state gossip")
-    finally:
-        for r in regs:
-            r.close()
-        for e in engines:
-            try:
-                e.shutdown()
-            except Exception:
-                pass

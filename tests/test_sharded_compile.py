@@ -1,4 +1,4 @@
-"""Sharded-compile tests: the dry-run machinery on a small real device
+"""Sharded-compile tests: sharded lowering on a small real device
 mesh (8 host devices in a subprocess), covering train/prefill/decode
 lowering for a dense and a MoE arch, plus the mesh constructors."""
 import subprocess
@@ -115,7 +115,7 @@ def test_sharded_train_and_decode():
 
 
 def test_production_mesh_shapes():
-    # AbstractMesh mirrors make_production_mesh without touching devices
+    # production-sized (pod, data, model) meshes, without touching devices
     from repro.distrib.sharding import abstract_mesh
     single = abstract_mesh((16, 16), ("data", "model"))
     multi = abstract_mesh((2, 16, 16), ("pod", "data", "model"))

@@ -156,6 +156,55 @@ def test_cross_shard_routing_and_services_merge():
             e.shutdown()
 
 
+@pytest.mark.parametrize("m", [1, 2])
+def test_concurrent_writers_land_every_registration(m):
+    """Writer threads sharing one client engine register instances
+    across the shards, re-register them on new addresses and report
+    their load: no call fails, and every instance resolves on its owning
+    shard afterwards with its last address and load."""
+    engines, regs, spec = _mk_shards(m, instance_ttl=30.0)
+    cli = Engine("tcp://127.0.0.1:0")
+    services = [f"scale-{i:02d}" for i in range(16)]
+    n, n_threads = 200, 4
+    errors, landed = [], [[] for _ in range(n_threads)]
+
+    def writer(t):
+        c = ShardedRegistryClient(cli, spec, timeout=5.0)
+        for i in range(t, n, n_threads):
+            svc = services[i % len(services)]
+            try:
+                iid = c.register(svc, [f"tcp://10.0.0.1:{i}"], capacity=4)
+                c.register(svc, [f"tcp://10.0.0.2:{i}"], capacity=4,
+                           iid=iid)
+                c.report(svc, iid, 0.5)
+                landed[t].append(iid)
+            except Exception as e:  # noqa: BLE001 — surfaced below
+                errors.append(repr(e))
+
+    try:
+        threads = [threading.Thread(target=writer, args=(t,), daemon=True)
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not errors, errors[:3]
+        iids = [iid for mine in landed for iid in mine]
+        assert len(set(iids)) == n
+        c = ShardedRegistryClient(cli, spec, timeout=5.0)
+        seen = [inst for svc in services
+                for inst in c.resolve(svc, fresh=True)["instances"]]
+        assert sorted(i["iid"] for i in seen) == sorted(iids)
+        assert all(i["uris"][0].startswith("tcp://10.0.0.2:")
+                   and i["load"] == 0.5 for i in seen)
+        assert {c.shard_of(svc) for svc in services} == set(range(m))
+    finally:
+        for r in regs:
+            r.close()
+        for e in engines + [cli]:
+            e.shutdown()
+
+
 def test_pool_and_service_instance_route_through_sharded_spec():
     """ServicePool + ServiceInstance take the '|' spec unchanged: both
     bind to the owning shard and the data path works end to end."""

@@ -224,14 +224,3 @@ def test_rglru_recurrentgemma_width(one_chip, batch):
     c = _compile(lambda x, r, i, ll, h0: rglru_pallas(x, r, i, ll, h0),
                  *args)
     assert "tpu_custom_call" in c.as_text()
-
-
-
-def test_peak_table_keyed_by_device_kind(one_chip):
-    """The roofline peaks are found under the kind JAX reports for a v5e,
-    and an unknown kind is an error, never a default."""
-    from repro.launch.mesh import hw_for
-    kind = next(iter(one_chip.device_set)).device_kind
-    assert hw_for(kind)["peak_flops_bf16"] == 197e12
-    with pytest.raises(ValueError, match="no published peaks"):
-        hw_for("TPU v0 unknown")
