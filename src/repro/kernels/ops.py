@@ -396,6 +396,75 @@ def router_topk(logits, k: int, *, impl: str = "auto"):
 
 
 # ===========================================================================
+# grouped matmul (dropless MoE experts)
+# ===========================================================================
+def grouped_matmul(lhs, rhs, group_sizes, *, layer=None,
+                   impl: str = "auto"):
+    """Each group of rows of ``lhs`` times its own matrix. lhs: (M, K);
+    rhs: (G, K, N), or with ``layer`` a stack (L, G, K, N) of which
+    layer ``layer``'s G matrices are used; group_sizes: (G,) int32, the
+    groups' rows in order from row 0, summing to at most M.  Returns
+    (M, N) float32; rows past the last group are 0.
+
+    ``pallas`` is the megablox kernel (``gmm``): a grid over (row tile,
+    group) pairs that visits only the tiles holding a group's rows, each
+    group's whole (K, N) matrix one block, so a group's weights are read
+    once however many tiles its rows span.  Given a stack, the kernel
+    reads the layer's matrices where they lie: the stack is one (L·G)
+    group axis and ``gmm``'s group offset, −layer·G, shifts each group's
+    block index to ``group + layer·G``.  A per-layer slice would be an
+    operand of its own, copied out of the stack before every call.
+    ``xla`` is ``lax.ragged_dot``; ``ref`` the oracle."""
+    impl = resolve_impl(impl)
+    M = lhs.shape[0]
+    if impl in ("pallas", "interpret"):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        tm, m_pad = _gmm_tile(M)
+        K, N = rhs.shape[-2:]
+        offset = None
+        if layer is not None:
+            G = rhs.shape[1]
+            rhs = rhs.reshape(-1, K, N)
+            offset = (-G * jnp.asarray(layer)).astype(jnp.int32)
+        out = gmm(jnp.pad(lhs, ((0, m_pad - M), (0, 0))), rhs,
+                  group_sizes, preferred_element_type=jnp.float32,
+                  tiling=(tm, K, N), group_offset=offset,
+                  interpret=(impl == "interpret"))[:M]
+    else:
+        if layer is not None:
+            rhs = rhs[layer]
+        if impl == "ref":
+            return ref_mod.grouped_matmul_ref(lhs, rhs, group_sizes)
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                 preferred_element_type=jnp.float32)
+    rows = jnp.arange(M)[:, None]
+    return jnp.where(rows < jnp.sum(group_sizes), out, 0.0)
+
+
+def _gmm_tile(m: int) -> Tuple[int, int]:
+    """The kernel's row tile for ``m`` rows, and the rows it runs over:
+    ``m`` padded to 16 (a bfloat16 tile's rows), then the largest tile
+    of at most 128 rows that divides that."""
+    m_pad = -(-m // 16) * 16
+    tm = next(t for t in (128, 64, 32, 16) if m_pad % t == 0)
+    return tm, m_pad
+
+
+def grouped_matmul_rows(m: int, groups: int, impl: str = "auto") -> int:
+    """Rows :func:`grouped_matmul` multiplies for ``m`` rows of lhs in
+    ``groups`` groups: the kernel's ``m`` padded to its row tile;
+    ``lax.ragged_dot``'s ``m`` on TPU, and off it, where it lowers to a
+    dense product of every group's matrix with every row, masked,
+    ``groups × m``."""
+    impl = resolve_impl(impl)
+    if impl in ("pallas", "interpret"):
+        return _gmm_tile(m)[1]
+    if impl == "xla" and jax.default_backend() != "tpu":
+        return groups * m
+    return m
+
+
+# ===========================================================================
 # Fletcher-64
 # ===========================================================================
 def fletcher64(buf, *, impl: str = "auto", block: int = 1024) -> int:

@@ -195,6 +195,27 @@ def router_topk_ref(logits: jax.Array, k: int, *,
 
 
 # ---------------------------------------------------------------------------
+# grouped matmul (dropless MoE experts)
+# ---------------------------------------------------------------------------
+def grouped_matmul_ref(lhs: jax.Array, rhs: jax.Array,
+                       group_sizes: jax.Array) -> jax.Array:
+    """Grouped matmul oracle: each row of ``lhs`` times its own group's
+    matrix, one row at a time.
+
+    lhs: (M, K); rhs: (G, K, N); group_sizes: (G,), the groups' rows in
+    order from row 0, summing to at most M.  Returns (M, N) f32; rows
+    past the last group are 0.
+    """
+    M = lhs.shape[0]
+    G = rhs.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    g = jnp.searchsorted(ends, jnp.arange(M), side="right")   # (M,)
+    w = rhs[jnp.minimum(g, G - 1)].astype(jnp.float32)          # (M,K,N)
+    out = jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32), w)
+    return jnp.where((g < G)[:, None], out, 0.0)
+
+
+# ---------------------------------------------------------------------------
 # Fletcher-64 checksum (bulk/checkpoint integrity — the RPC layer's hot loop)
 # ---------------------------------------------------------------------------
 FLETCHER_MOD = (1 << 32) - 1

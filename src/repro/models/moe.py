@@ -4,18 +4,23 @@ Expert parallelism maps experts onto the ``model`` mesh axis.  Because TP
 activations are replicated across ``model`` at block boundaries, every
 model shard already holds its row's tokens — so instead of the classic
 all-to-all dispatch, each shard (a) computes the router for its row's
-tokens (tiny, redundant across shards), (b) sort-dispatches only the
-assignments that route to *its* local experts into an (E_local, C, d)
-capacity buffer, (c) runs its expert FFNs, (d) scatter-combines partial
-outputs, and (e) all-reduces over ``model`` — the same psum a dense TP
-MLP needs anyway.  Net effect: MoE costs one (T_local, d) all-reduce, no
-all-to-all, no token-size-dependent resharding.  (Recorded in DESIGN.md
-as a TPU adaptation; the classic a2a dispatch is what the GPU literature
-uses.)
+tokens (tiny, redundant across shards), (b) dispatches only the
+assignments that route to *its* local experts, (c) runs its expert FFNs,
+(d) combines partial outputs, and (e) all-reduces over ``model`` — the
+same psum a dense TP MLP needs anyway.  Net effect: MoE costs one
+(T_local, d) all-reduce, no all-to-all, no token-size-dependent
+resharding.  (Recorded in DESIGN.md as a TPU adaptation; the classic a2a
+dispatch is what the GPU literature uses.)
 
-Token dropping: per-expert capacity C = ceil(T_local·k/E · cf); dropped
-assignments fall out of the scatter (mode="drop") and contribute zero,
-exactly like Switch-style capacity dispatch.
+Two dispatches, chosen by whether the caller may drop tokens:
+
+  * dropless (serving): the T_local·k assignments sorted by expert into
+    one row block, and each expert's FFN a grouped matmul over its own
+    rows only (``ops.grouped_matmul``) — no capacity buffer;
+  * capacity (training): per-expert capacity C = ceil(T_local·k/E · cf)
+    rows of an (E_local, C, d) buffer; dropped assignments fall out of
+    the scatter (mode="drop") and contribute zero, exactly like
+    Switch-style capacity dispatch.
 
 The same code runs without a mesh (``spmd=None``) for CPU smoke tests.
 """
@@ -57,15 +62,9 @@ def padded_experts(cfg: ModelConfig, n_shards: int) -> int:
     return int(math.ceil(e / n_shards) * n_shards)
 
 
-def capacity(cfg: ModelConfig, tokens: int, capacity_factor: float,
-             dropless: bool) -> int:
-    """Rows per expert of the dispatch buffer for ``tokens`` local
-    tokens.  Dropless: every expert can hold every assignment it could
-    receive (each token contributes at most one assignment per expert) —
-    used for serving, where per-step dropping would make decode diverge
-    from prefill."""
-    if dropless:
-        return tokens
+def capacity(cfg: ModelConfig, tokens: int, capacity_factor: float) -> int:
+    """Rows per expert of the capacity dispatch's buffer for ``tokens``
+    local tokens."""
     return max(int(math.ceil(tokens * cfg.moe.top_k
                              / max(cfg.moe.num_experts, 1)
                              * capacity_factor)), 1)
@@ -94,31 +93,66 @@ def moe_params(cfg: ModelConfig, rng, path, e_pad: Optional[int] = None) -> dict
     return p
 
 
+EXPERT_KEYS = ("wi_gate", "wi_up", "wo")
+
+
+def with_stacked_experts(p: dict, stack: dict, layer) -> dict:
+    """One layer's MoE params ``p`` with its expert matrices swapped for
+    the whole layer stack's, ``stack``'s (L, E, ·, ·), and the layer's
+    index under ``"layer"``.  The dropless grouped matmul then reads the
+    layer's experts where they lie in the stack; a per-layer slice fed
+    to the kernel is a copy of every expert's weights, every layer."""
+    return dict(p, **{n: stack[n] for n in EXPERT_KEYS}, layer=layer)
+
+
+def _own_layer(p: dict) -> dict:
+    """``p`` with stacked experts (:func:`with_stacked_experts`) cut back
+    to its own layer's."""
+    layer = p["layer"]
+    return {n: (v[layer] if n in EXPERT_KEYS else v)
+            for n, v in p.items() if n != "layer"}
+
+
+def _glu(cfg: ModelConfig, gate, up):
+    if cfg.mlp == "geglu":
+        return jax.nn.gelu(gate, approximate=True) * up
+    return jax.nn.silu(gate) * up
+
+
 def _expert_ffn(cfg: ModelConfig, p: dict, buf):
     """buf: (E_l, C, d) -> (E_l, C, d), swiglu experts."""
     cdt = jnp.dtype(cfg.compute_dtype)
     x = buf.astype(cdt)
     gate = jnp.einsum("ecd,edf->ecf", x, p["wi_gate"].astype(cdt))
     up = jnp.einsum("ecd,edf->ecf", x, p["wi_up"].astype(cdt))
-    if cfg.mlp in ("swiglu",):
-        h = jax.nn.silu(gate) * up
-    elif cfg.mlp == "geglu":
-        h = jax.nn.gelu(gate, approximate=True) * up
-    else:
-        h = jax.nn.silu(gate) * up
-    return jnp.einsum("ecf,efd->ecd", h, p["wo"].astype(cdt))
+    return jnp.einsum("ecf,efd->ecd", _glu(cfg, gate, up),
+                      p["wo"].astype(cdt))
+
+
+def _grouped_ffn(cfg: ModelConfig, p: dict, rows, group_sizes, impl):
+    """rows: (R, d), sorted by local expert, group_sizes[e] of them for
+    expert e -> (R, d), each row through its own expert's gated FFN;
+    rows past the last group come out 0."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+
+    def mm(a, w):
+        return ops.grouped_matmul(a, w.astype(cdt), group_sizes,
+                                  layer=p.get("layer"),
+                                  impl=impl).astype(cdt)
+
+    x = rows.astype(cdt)
+    h = _glu(cfg, mm(x, p["wi_gate"]), mm(x, p["wi_up"]))
+    return mm(h, p["wo"])
 
 
 def _moe_local(cfg: ModelConfig, params: dict, x2d, *, e_start, e_local,
                e_pad: int, capacity_factor: float, dropless: bool = False,
-               router_impl: str = "auto"):
+               impl: str = "auto"):
     """Dispatch + expert FFN for one shard. x2d: (T_l, d) local tokens;
     expert tensors hold [e_start, e_start+e_local). Returns partial y
     (contributions of local experts only) and local-sum aux stats."""
-    T, d = x2d.shape
     E_real, k = cfg.moe.num_experts, cfg.moe.top_k
     cdt = jnp.dtype(cfg.compute_dtype)
-    C = capacity(cfg, T, capacity_factor, dropless)
 
     with jax.named_scope("moe.route"):
         logits = x2d.astype(cdt) @ params["router"].astype(cdt)  # (T, E_pad)
@@ -126,8 +160,7 @@ def _moe_local(cfg: ModelConfig, params: dict, x2d, *, e_start, e_local,
         if e_pad > E_real:
             pad_mask = jnp.arange(e_pad) >= E_real
             logits = jnp.where(pad_mask[None], -1e30, logits)
-        w, idx, probs = ops.router_topk(logits, k,
-                                        impl=router_impl)       # (T,k)
+        w, idx, probs = ops.router_topk(logits, k, impl=impl)  # (T,k)
 
         # aux stats (sums; caller normalizes / psums): load per expert,
         # mean prob per expert, router z
@@ -135,6 +168,53 @@ def _moe_local(cfg: ModelConfig, params: dict, x2d, *, e_start, e_local,
         load_sum = assign_oh.sum(0)                               # (E,)
         prob_sum = probs.sum(0)                                   # (E,)
         z_sum = jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum()
+
+    if dropless:
+        y = _dispatch_grouped(cfg, params, x2d, w, idx, e_start=e_start,
+                              e_local=e_local, impl=impl)
+    else:
+        y = _dispatch_capacity(cfg, params, x2d, w, idx, e_start=e_start,
+                               e_local=e_local, e_pad=e_pad,
+                               capacity_factor=capacity_factor)
+    return y, (load_sum, prob_sum, z_sum, jnp.float32(x2d.shape[0]))
+
+
+def _dispatch_grouped(cfg: ModelConfig, params: dict, x2d, w, idx, *,
+                      e_start, e_local, impl):
+    """Dropless: the T·k assignments sorted by local expert into one
+    (T·k, d) row block, each expert's FFN multiplied over its own rows
+    only (a grouped matmul).  Assignments to another shard's experts sort
+    last, outside every group, and add zero."""
+    T, d = x2d.shape
+    k = idx.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        local_e = idx.reshape(-1) - e_start                       # (T*k,)
+        in_shard = (local_e >= 0) & (local_e < e_local)
+        key = jnp.where(in_shard, local_e, e_local)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=e_local).astype(jnp.int32)
+        rows = x2d[order // k]                    # flat index = t·k + j
+
+    with jax.named_scope("moe.experts"):
+        out = _grouped_ffn(cfg, params, rows, group_sizes, impl)  # (T*k,d)
+
+    with jax.named_scope("moe.combine"):
+        # each assignment's row back in (token, choice) order, a gather
+        # through the inverse permutation, then weighted and summed over
+        # the token's k choices
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+        vals = out[inv].reshape(T, k, d)
+        wk = jnp.where(in_shard, w.reshape(-1), 0.0).reshape(T, k)
+        return jnp.einsum("tkd,tk->td", vals, wk.astype(vals.dtype))
+
+
+def _dispatch_capacity(cfg: ModelConfig, params: dict, x2d, w, idx, *,
+                       e_start, e_local, e_pad, capacity_factor):
+    """Capacity-factor dispatch (training): each expert holds at most C
+    rows of an (E_l, C, d) buffer; assignments past C are dropped."""
+    T, d = x2d.shape
+    k = idx.shape[1]
+    C = capacity(cfg, T, capacity_factor)
 
     with jax.named_scope("moe.dispatch"):
         flat_e = idx.reshape(-1)                                  # (T*k,)
@@ -173,8 +253,7 @@ def _moe_local(cfg: ModelConfig, params: dict, x2d, *, e_start, e_local,
         vals = out_buf.at[scat_e, scat_c].get(
             mode="fill", fill_value=0.0)                          # (T*k,d)
         vals = vals * jnp.where(keep, sw, 0.0)[:, None].astype(vals.dtype)
-        y = jnp.zeros((T, d), vals.dtype).at[st].add(vals)
-    return y, (load_sum, prob_sum, z_sum, jnp.float32(T))
+        return jnp.zeros((T, d), vals.dtype).at[st].add(vals)
 
 
 def _aux_from_stats(cfg: ModelConfig, load_sum, prob_sum, z_sum, t_total):
@@ -192,18 +271,25 @@ def moe_apply(cfg: ModelConfig, params: dict, x, *,
               spmd: Optional[MoESpmd] = None,
               capacity_factor: Optional[float] = None,
               dropless: bool = False,
-              router_impl: str = "auto") -> Tuple[jax.Array, dict]:
-    """MoE FFN over x: (B,S,d). Returns (y, aux_losses)."""
+              impl: str = "auto") -> Tuple[jax.Array, dict]:
+    """MoE FFN over x: (B,S,d). Returns (y, aux_losses).  ``params`` may
+    hold the whole stack's experts (:func:`with_stacked_experts`)."""
     B, S, d = x.shape
     x2d = x.reshape(B * S, d)
     cf = capacity_factor if capacity_factor is not None \
         else cfg.moe.capacity_factor
+    if "layer" in params and (
+            spmd is not None or not dropless
+            or params["wi_gate"].dtype != jnp.dtype(cfg.compute_dtype)):
+        # stacked experts are read in place by the dropless path alone,
+        # and only uncast: a cast of the stack would repeat every layer
+        params = _own_layer(params)
 
     if spmd is None:
-        e_pad = params["wi_gate"].shape[0]
+        e_pad = params["wi_gate"].shape[-3]
         y, (ls, ps, zs, t) = _moe_local(
             cfg, params, x2d, e_start=0, e_local=e_pad, e_pad=e_pad,
-            capacity_factor=cf, dropless=dropless, router_impl=router_impl)
+            capacity_factor=cf, dropless=dropless, impl=impl)
         if "shared" in params:
             y = y + mlp_apply(cfg, params["shared"], x2d)
         aux = _aux_from_stats(cfg, ls, ps, zs, t)
@@ -224,7 +310,7 @@ def moe_apply(cfg: ModelConfig, params: dict, x, *,
         p_loc = {"router": router, "wi_gate": wig, "wi_up": wiu, "wo": wo}
         y, (ls, ps, zs, t) = _moe_local(
             cfg, p_loc, x_loc, e_start=my, e_local=e_local, e_pad=e_pad,
-            capacity_factor=cf, dropless=dropless, router_impl=router_impl)
+            capacity_factor=cf, dropless=dropless, impl=impl)
         if has_shared:
             sp = dict(zip(sorted(shared.keys()), shared_w))
             y = y + mlp_apply(cfg, sp, x_loc)      # mlp dim sharded -> partial

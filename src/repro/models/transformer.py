@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ATTN_KINDS, ModelConfig
 from . import attention as attn_mod
+from ..kernels import ops
 from . import moe as moe_mod
 from . import rglru_block, ssd_block
 from .common import (P, dense_p, embed_params, embed_tokens, chunked_ce_loss,
@@ -77,7 +78,7 @@ def _ffn(cfg, p, x, *, spmd, capacity_factor, impl, dropless=False):
     if "moe" in p:
         return moe_mod.moe_apply(cfg, p["moe"], x, spmd=spmd,
                                  capacity_factor=capacity_factor,
-                                 dropless=dropless, router_impl=impl)
+                                 dropless=dropless, impl=impl)
     return mlp_apply(cfg, p["mlp"], x), {}
 
 
@@ -202,6 +203,16 @@ def _into_layer(cache, state, layer):
         return state
     return {n: jax.lax.dynamic_update_index_in_dim(
         cache[n], state[n].astype(cache[n].dtype), layer, 0) for n in state}
+
+
+def _experts_in_place(layer_p, stacks, layer):
+    """A scanned period's block params, each MoE block's expert matrices
+    swapped for the stack's in ``stacks`` (``params["periods"]``) and
+    ``layer``, the scan's index: serving reads a layer's experts where
+    they lie (:func:`moe.with_stacked_experts`)."""
+    return tuple(
+        dict(p, moe=moe_mod.with_stacked_experts(p["moe"], s["moe"], layer))
+        if "moe" in p else p for p, s in zip(layer_p, stacks))
 
 
 # ===========================================================================
@@ -426,15 +437,17 @@ class Model:
         plen = len(cfg.period)
 
         def period_body(h, xs):
+            layer_p, layer = xs
+            layer_p = _experts_in_place(layer_p, params["periods"], layer)
             cs = []
             for pos in range(plen):
-                h, c = apply_one(h, xs[pos], cfg.period[pos])
+                h, c = apply_one(h, layer_p[pos], cfg.period[pos])
                 cs.append(c)
             return h, tuple(cs)
 
         if self.n_scan_periods:
-            h, cache["periods"] = jax.lax.scan(period_body, h,
-                                               params["periods"])
+            h, cache["periods"] = jax.lax.scan(
+                period_body, h, (params["periods"], self._moe_layer_ids()))
         else:
             cache["periods"] = ()
 
@@ -486,6 +499,7 @@ class Model:
         def period_body(carry, xs):
             h, stacks = carry
             layer_p, layer = xs
+            layer_p = _experts_in_place(layer_p, params["periods"], layer)
             stacks = list(stacks)
             for posn in range(plen):
                 h, stacks[posn] = apply_one(h, layer_p[posn],
@@ -513,6 +527,14 @@ class Model:
         return logits, new_cache
 
     # ------------------------------------------------------------ MoE work
+    def _moe_layer_ids(self):
+        """The layer scan's index as a scanned input, for the MoE blocks
+        to find their experts in the stack (:func:`_experts_in_place`);
+        None, an input of no arrays, for a model without experts."""
+        if not self.moe_layers:
+            return None
+        return jnp.arange(self.n_scan_periods, dtype=jnp.int32)
+
     @property
     def moe_layers(self) -> int:
         """Layers whose feed-forward half is the mixture of experts."""
@@ -521,15 +543,17 @@ class Model:
             return 0
         return cfg.n_layers - self.prefix_count
 
-    def moe_rows(self, tokens: int) -> int:
+    def moe_rows(self, tokens: int, impl: str = "auto") -> int:
         """Expert-FFN rows that a serving call over ``tokens`` tokens
-        computes, summed over the stack: padded experts × the dropless
-        dispatch's capacity × MoE layers."""
+        computes, summed over the stack: in each MoE layer the dropless
+        dispatch's grouped matmul over the call's tokens × top-k routed
+        rows, as the ``impl`` path multiplies them (the kernel pads them
+        to its row tile)."""
         if not self.moe_layers:
             return 0
-        C = moe_mod.capacity(self.cfg, tokens, self.cfg.moe.capacity_factor,
-                             dropless=True)
-        return self.e_pad * C * self.moe_layers
+        rows = ops.grouped_matmul_rows(tokens * self.cfg.moe.top_k,
+                                       self.e_pad, impl)
+        return rows * self.moe_layers
 
     # ------------------------------------------------------------ chunked
     @property
@@ -574,7 +598,8 @@ class Model:
         plen = len(cfg.period)
 
         def period_body(h, xs):
-            layer_p, layer_c = xs
+            layer_p, layer_c, layer = xs
+            layer_p = _experts_in_place(layer_p, params["periods"], layer)
             ncs = []
             for posn in range(plen):
                 h, nc = apply_one(h, layer_p[posn], cfg.period[posn],
@@ -584,7 +609,9 @@ class Model:
 
         if self.n_scan_periods:
             h, new_cache["periods"] = jax.lax.scan(
-                period_body, h, (params["periods"], cache["periods"]))
+                period_body, h,
+                (params["periods"], cache["periods"],
+                 self._moe_layer_ids()))
         else:
             new_cache["periods"] = ()
 

@@ -611,7 +611,8 @@ class ServeEngine:
         the model's dispatch computes for a call of that size."""
         if not self._moe_per_token:
             return
-        n, rows = real * self._moe_per_token, self.model.moe_rows(tokens)
+        n = real * self._moe_per_token
+        rows = self.model.moe_rows(tokens, self.impl)
         self.moe_assignments += n
         self.moe_rows += rows
         _M_MOE_ASSIGNMENTS.inc(n)

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.kernels import ops
 from repro.models import moe as moe_mod
 from repro.models.common import unzip
 
@@ -27,21 +28,18 @@ def tiny_cfg(E=8, k=2, shared=0):
 
 
 def dense_gather_oracle(cfg, params, x2d):
-    """Reference: per-token gather of expert FFNs (no capacity)."""
+    """Reference: each token through its own top-k experts' FFNs,
+    gathered per token (no capacity, no dispatch); padded experts are
+    never routed to."""
     logits = x2d @ params["router"]
-    w, idx, _ = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.moe.top_k), None, None
+    real = jnp.arange(logits.shape[-1]) < cfg.moe.num_experts
+    logits = jnp.where(real, logits, -1e30)
     w, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.moe.top_k)
     w = w / w.sum(-1, keepdims=True)
-    y = jnp.zeros_like(x2d)
-    for t in range(x2d.shape[0]):
-        acc = jnp.zeros((x2d.shape[1],))
-        for j in range(cfg.moe.top_k):
-            e = idx[t, j]
-            g = x2d[t] @ params["wi_gate"][e]
-            u = x2d[t] @ params["wi_up"][e]
-            acc = acc + w[t, j] * ((jax.nn.silu(g) * u) @ params["wo"][e])
-        y = y.at[t].set(acc)
-    return y
+    g = jnp.einsum("td,tkdf->tkf", x2d, params["wi_gate"][idx])
+    u = jnp.einsum("td,tkdf->tkf", x2d, params["wi_up"][idx])
+    return jnp.einsum("tkf,tkfd,tk->td", jax.nn.silu(g) * u,
+                      params["wo"][idx], w)
 
 
 @cases(5)
@@ -56,6 +54,99 @@ def test_dropless_equals_dense_gather(rng):
     want = dense_gather_oracle(cfgf, params, x[0])
     np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+def _route_to(params, experts):
+    """Router weights under which every token of a positive input picks
+    ``experts``, in that order, and no other."""
+    col = np.zeros(params["router"].shape, np.float32)
+    for rank, e in enumerate(experts):
+        col[:, e] = 4.0 / (rank + 1)
+    return dict(params, router=jnp.asarray(col))
+
+
+# (E, k, e_pad, T, routing, impl): Granite's 40 experts top-8 at reduced
+# width at a decode step's and a chunk's tokens, also through the TPU
+# kernel's body; fewer tokens than experts (empty groups); one expert
+# pair taking every token; padded experts; a token count that is a
+# multiple of no tile
+GROUPED_CASES = {
+    "granite_T24": (40, 8, None, 24, None, "xla"),
+    "granite_T256": (40, 8, None, 256, None, "xla"),
+    "granite_T24_kernel": (40, 8, None, 24, None, "interpret"),
+    "empty_groups": (16, 2, None, 3, None, "xla"),
+    "all_to_one_expert": (8, 2, None, 16, (5, 2), "xla"),
+    "padded_experts": (5, 2, 8, 16, None, "xla"),
+    "untiled_T": (8, 2, None, 37, None, "interpret"),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_grouped_dropless_equals_per_token_reference(case):
+    """The dropless dispatch (a grouped matmul over the routed rows)
+    gives each token exactly its top-k experts' weighted outputs."""
+    E, k, e_pad, T, routing, impl = GROUPED_CASES[case]
+    cfg = tiny_cfg(E=E, k=k).replace(compute_dtype="float32")
+    params, _ = unzip(moe_mod.moe_params(cfg, RNG, ("moe",), e_pad=e_pad))
+    x = jax.random.normal(jax.random.PRNGKey(T), (1, T, 32))
+    if routing is not None:
+        params = _route_to(params, routing)
+        x = jnp.abs(x)
+    y, _ = moe_mod.moe_apply(cfg, params, x, dropless=True, impl=impl)
+    want = dense_gather_oracle(cfg, params, x[0])
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+# group sizes over M rows: uneven, empty groups among them, one group
+# taking every row, rows past the last group, a row count of no tile
+GROUP_SIZES = {
+    "uneven": (24, [5, 0, 9, 1, 0, 9]),
+    "one_group": (16, [0, 0, 16, 0]),
+    "rows_past_groups": (20, [3, 4, 0, 6]),
+    "untiled_M": (37, [11, 0, 13, 13]),
+}
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("case", list(GROUP_SIZES))
+def test_grouped_matmul_matches_oracle(case, impl):
+    M, sizes = GROUP_SIZES[case]
+    kl, kr = jax.random.split(jax.random.PRNGKey(M))
+    lhs = jax.random.normal(kl, (M, 24))
+    rhs = jax.random.normal(kr, (len(sizes), 24, 40))
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = ops.grouped_matmul(lhs, rhs, gs, impl=impl)
+    want = ops.grouped_matmul(lhs, rhs, gs, impl="ref")
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    end = sum(sizes)
+    assert not np.any(np.asarray(got)[end:])
+    # the oracle itself: the first row of the last group that has rows
+    g = max(i for i, n in enumerate(sizes) if n)
+    r = sum(sizes[:g])
+    np.testing.assert_allclose(np.asarray(want[r]),
+                               np.asarray(lhs[r] @ rhs[g]), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret", "ref"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_grouped_matmul_reads_a_layer_of_the_stack(layer, impl):
+    """Given every layer's matrices stacked (L, G, K, N) and a traced
+    layer index, as a layer scan hands them, the product is the one with
+    that layer's matrices alone."""
+    M, sizes = GROUP_SIZES["uneven"]
+    kl, kr = jax.random.split(jax.random.PRNGKey(layer))
+    lhs = jax.random.normal(kl, (M, 24))
+    stack = jax.random.normal(kr, (3, len(sizes), 24, 40))
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = jax.jit(lambda lay: ops.grouped_matmul(
+        lhs, stack, gs, layer=lay, impl=impl))(jnp.int32(layer))
+    want = ops.grouped_matmul(lhs, stack[layer], gs, impl="ref")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_capacity_monotone_drops():

@@ -254,9 +254,12 @@ def test_work_counters_and_phases(model_and_params):
 def test_moe_work_counters(chunk):
     """A MoE engine counts, from shapes alone, the routed assignments of
     its real tokens (tokens × k × MoE layers) and the expert rows its
-    dropless dispatch computes (E × C × layers, C the tokens of the call:
-    a chunk's size, the slots of a decode step, a whole prompt without
-    chunks), in stats() and in the registry's counters alike."""
+    dropless dispatch's grouped matmul multiplies: the call's T × k
+    routed rows in each layer, T the tokens of the call (a chunk's size,
+    the slots of a decode step, a whole prompt without chunks), which
+    off the TPU ``lax.ragged_dot`` multiplies with every expert's
+    matrix (E × T × k × layers), in stats() and in the registry's
+    counters alike."""
     from repro.telemetry import metrics
     cfg = configs.reduced("granite-moe-3b-a800m").replace(
         compute_dtype="float32")
@@ -276,9 +279,27 @@ def test_moe_work_counters(chunk):
         (prompt_tokens + st["decode_tokens"]) * k * L
     prefill_rows = st["prefill_chunks"] * chunk if chunk else prompt_tokens
     assert st["moe_rows"] == \
-        (prefill_rows + st["decode_steps"] * n_slots) * E * L
+        (prefill_rows + st["decode_steps"] * n_slots) * E * k * L
     for name, c in reg.items():
         assert c.value - before[name] == st[name], name
+
+
+@pytest.mark.parametrize("impl,tokens,rows", [
+    ("interpret", 24, 24 * 8),      # a decode step: 192 rows, one tile
+    ("interpret", 256, 256 * 8),    # a chunk: 2048 rows
+    ("interpret", 3, 32),           # 24 rows padded to the 16-row tile
+    ("ref", 3, 24),
+    ("xla", 3, 24 * 40),            # off the TPU: every expert, every row
+])
+def test_moe_rows_are_the_grouped_matmuls_rows(impl, tokens, rows):
+    """``Model.moe_rows``: a call's tokens × top-k routed rows in each
+    MoE layer, as the path multiplies them: the kernel pads them to its
+    row tile, the oracle takes them as they are, and ``lax.ragged_dot``
+    off the TPU is a masked product of every expert with every row."""
+    cfg = configs.get("granite-moe-3b-a800m")
+    m = Model(cfg)
+    assert m.moe_rows(tokens, impl) == rows * cfg.n_layers
+    assert Model(configs.get("qwen1.5-0.5b")).moe_rows(tokens, impl) == 0
 
 
 def test_dense_engine_counts_no_moe_work(model_and_params):

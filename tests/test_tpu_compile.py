@@ -192,6 +192,46 @@ def test_sampler_compiles_at_vocab_width(one_chip, name, shape, rows):
     assert c.memory_analysis().temp_size_in_bytes < HBM_BYTES
 
 
+@pytest.mark.parametrize("call", ["decode", "chunk"])
+def test_granite_dropless_moe_is_a_grouped_product(one_chip, call):
+    """Granite-3.0 MoE at published widths (40 experts of 1536×512,
+    top-8, bfloat16 weights) as serving runs it, a 24-slot decode step
+    and a 256-token chunk: each layer's experts are three grouped-matmul
+    kernels over the routed rows, nothing of the shape (experts, tokens,
+    ·) is left (no dispatch buffer, no dense product of every expert
+    with every token), and the kernels read the layer's experts in the
+    stack of all 32 layers (1280 matrices) where they lie: no layer's
+    experts are copied out."""
+    cfg = configs.get("granite-moe-3b-a800m").replace(
+        param_dtype="bfloat16")
+    E, d, f = cfg.moe.num_experts, cfg.d_model, cfg.d_ff
+    model = Model(cfg)
+    params = _on(one_chip, unzip(jax.eval_shape(model.init,
+                                                jax.random.PRNGKey(0)))[0])
+    slots, tokens = (24, 1) if call == "decode" else (1, 256)
+    cache = _on(one_chip, unzip(jax.eval_shape(
+        lambda: model.cache_specs(slots, 2048)))[0])
+    toks = _sds(one_chip, (slots, tokens), jnp.int32)
+    if call == "decode":
+        c = decode_executable(model, "pallas", donate=True).lower(
+            params, cache, toks, _sds(one_chip, (slots,), jnp.int32))
+    else:
+        c = jax.jit(lambda p, c, t, o: model.prefill_chunk(
+            p, c, t, o, impl="pallas")).lower(
+                params, cache, toks, _sds(one_chip, (), jnp.int32))
+    text = c.compile().as_text()
+    gmm = re.findall(r"%gmm[.\d]* = .*? custom-call\((.*?)\), custom_call",
+                     text)
+    assert len(gmm) == 3, gmm
+    stack = model.moe_layers * E
+    for operands in gmm:
+        weights = re.search(r"%([\w.-]+)$", operands).group(1)
+        assert re.search(rf"%{re.escape(weights)} = bf16\[{stack},\d+,\d+\]",
+                         text), operands
+    assert not re.search(rf"\[{E},{slots * tokens},\d+\]", text)
+    assert not re.search(rf"= bf16\[{E},({d},{f}|{f},{d})\]", text)
+
+
 def test_router_topk_granite_width(one_chip):
     cfg = configs.get("granite-moe-3b-a800m")
     logits = _sds(one_chip, (4096, cfg.moe.num_experts), jnp.float32)
